@@ -21,6 +21,7 @@ import json
 import re
 import time
 import traceback
+from pathlib import Path
 from typing import Dict, List, Optional
 
 from benchmarks import (
@@ -39,6 +40,7 @@ from benchmarks import (
     fig_prefix_cache,
     table3_harvest_overhead,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "fig12": fig12_allocator,
@@ -90,6 +92,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     unknown = [s for s in args.suites if s not in SUITES]
     if unknown:
         ap.error(f"unknown suite(s) {unknown}; known: {', '.join(SUITES)}")
+    use_compile_cache(Path(__file__).resolve().parents[1])
     selected = args.suites or list(SUITES)
     print("name,us_per_call,derived")
     failures = []

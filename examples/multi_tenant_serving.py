@@ -6,7 +6,7 @@ control plane.
 
 Three layers run side by side:
 1. FUNCTIONAL: real token generation (greedy) through the JAX serving
-   engine for each tenant (reduced configs on CPU).
+   engine for each tenant (reduced smoke configs).
 2. TIMING/SLO (closed loop): the policy registry schedules the same
    tenants' traces on one NPU core under all four paper policies.
 3. ONLINE (open loop): a ServingSession admits Poisson request

@@ -2,7 +2,7 @@
 flash attention (prefill) and the MoE grouped matmul.
 
 Each kernel ships as <name>.py (pl.pallas_call + BlockSpec),
-ops.py (jit wrapper, interpret-mode fallback off-TPU), and
+ops.py (jit wrapper: Mosaic on TPU, interpret mode on CPU only), and
 ref.py (pure-jnp oracle used by the allclose test sweeps).
 
 The paper itself contributes no kernels (its mechanism is an ISA /

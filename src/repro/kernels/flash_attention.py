@@ -10,8 +10,8 @@ as μTOp granularity (DESIGN.md §3).
 GQA is handled in the index maps: query head h reads KV head
 h // (H // Hkv); KV blocks are never materialized per-Q-head.
 
-Validated with interpret=True against ``ref.attention_ref`` (this
-container has no TPU).
+Validated against ``ref.attention_ref`` in interpret mode on the CPU,
+and compiled for Mosaic on the TPU (``chip_smoke.py``).
 """
 from __future__ import annotations
 

@@ -6,7 +6,8 @@ accumulator tile lives in VMEM scratch across d-blocks. Block shapes
 are MXU-aligned (128 multiples); the (bc x bd) x (bd x bf) working
 set stays within a VMEM budget of a few MB.
 
-Validated with interpret=True against ``ref.grouped_matmul_ref``.
+Validated against ``ref.grouped_matmul_ref`` in interpret mode on the
+CPU, and compiled for Mosaic on the TPU (``chip_smoke.py``).
 """
 from __future__ import annotations
 

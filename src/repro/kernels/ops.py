@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on a
-real TPU the same code lowers to Mosaic. Padding to block multiples
-is handled here so callers pass natural shapes.
+On the TPU the kernels lower to Mosaic. On the CPU, where the tests
+run, they execute in Pallas interpret mode. Any other backend raises:
+no silent fallback hides which device ran them. Padding to block
+multiples is handled here so callers pass natural shapes.
 """
 from __future__ import annotations
 
@@ -16,7 +17,12 @@ from repro.kernels import moe_gmm
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on tpu (Mosaic) or on cpu (interpret "
+            f"mode), not on {backend!r}")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk"))

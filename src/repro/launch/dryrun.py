@@ -1,12 +1,13 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (architecture x input
 shape) on the 16x16 single-pod mesh AND the 2x16x16 multi-pod mesh,
 prove it fits (memory_analysis), and harvest the roofline terms
 (cost_analysis + post-SPMD collective bytes).
 
-The two lines above MUST stay the first statements in this module —
-jax locks the device count at first init.
+A compile-only tool on 512 forced host devices: it pins JAX to the CPU,
+even where the environment names a TPU platform, so it never takes a
+chip, and appends its device-count flag to any ``XLA_FLAGS`` already
+set. Both must happen before the first jax import — jax locks the
+platform and the device count at first init.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                # all cells
@@ -14,6 +15,13 @@ Usage:
       --shape train_4k --mesh multi                           # one cell
   ... --out results/dryrun.jsonl        (resumable: done cells skipped)
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
+
 import argparse
 import gc
 import json
@@ -172,6 +180,8 @@ def analyze_cell(
     bytes_accessed = colls.pop("hlo_bytes")
     coll_b = colls["total_weighted_bytes"]
 
+    # compile-target estimates from published v5e peaks, not a
+    # measurement of any device
     compute_s = flops / V5E.peak_flops_bf16
     memory_s = bytes_accessed / V5E.hbm_bw
     collective_s = coll_b / V5E.ici_bw

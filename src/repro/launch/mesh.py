@@ -8,20 +8,13 @@ from __future__ import annotations
 
 import jax
 
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults all
-    # mesh axes to Auto anyway, so omitting the kwarg is equivalent
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_local_mesh(model: int = 1):
@@ -29,4 +22,4 @@ def make_local_mesh(model: int = 1):
     n = len(jax.devices())
     data = n // model
     return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_type_kwargs(2))
+        (data, model), ("data", "model"), axis_types=(_AUTO,) * 2)

@@ -1,9 +1,9 @@
 """Hardware configurations.
 
 ``NPUCoreConfig`` mirrors the paper's Table II simulator config (a
-TPUv4-like core). ``TPUv5eRoofline`` carries the roofline constants
-the brief prescribes for the dry-run analysis (TPU v5e is the compile
-TARGET; this container only executes on CPU).
+TPUv4-like core). ``TPUv5eRoofline`` carries published TPU v5e peaks
+that the dry-run uses for compile-target estimates; they are not a
+measurement of the device a program runs on.
 """
 from __future__ import annotations
 
@@ -55,7 +55,8 @@ DEFAULT_CORE = NPUCoreConfig()
 
 @dataclass(frozen=True)
 class TPUv5eRoofline:
-    """Roofline constants for the dry-run target (per brief)."""
+    """Published per-chip peaks of the dry-run's compile target, for
+    roofline estimates only."""
 
     peak_flops_bf16: float = 197e12   # per chip
     hbm_bw: float = 819e9             # per chip

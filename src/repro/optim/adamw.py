@@ -1,4 +1,4 @@
-"""AdamW, hand-rolled (no optax in this container).
+"""AdamW, hand-rolled (the project does not depend on optax).
 
 Moments are fp32 regardless of param dtype; the update math runs in
 fp32 and casts back, so bf16 training (the dry-run configuration) is

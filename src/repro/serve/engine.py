@@ -35,6 +35,7 @@ class ServeEngine:
         self.cfg = cfg
         self.model: Model = build_model(cfg, remat=False)
         self.max_seq = max_seq
+        self.dtype = dtype
         if params is None:
             params = self.model.init(jax.random.PRNGKey(seed), dtype)
         self.params = params
@@ -57,7 +58,7 @@ class ServeEngine:
         B = toks.shape[0]
         S = toks.shape[-1]
         assert S + n_new <= self.max_seq, "increase max_seq"
-        cache = self.model.init_cache(B, self.max_seq)
+        cache = self.model.init_cache(B, self.max_seq, self.dtype)
         key = jax.random.PRNGKey(seed)
 
         t0 = time.time()

@@ -1,7 +1,7 @@
 """Training driver: jit'd train_step + data prefetch + async
 checkpointing + crash-resume + straggler monitoring.
 
-Single-process (this container has one CPU device); the same loop
+Single-process on whatever local devices exist; the same loop
 drives the production mesh when `mesh` is passed — steps are jit'd
 with the sharding rules from repro.distributed.sharding.
 """

@@ -70,6 +70,19 @@ def test_flash_attention_matches_model_sdpa():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Mosaic on the TPU, the interpreter on the CPU test platform; any
+    other backend raises rather than silently interpreting."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=backend):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("shape", [
     (4, 128, 256, 128),
