@@ -9,10 +9,12 @@ One process, no child processes, no options. Phases, in order:
    non-zero and names the platform found; there is no CPU path.
 2. serve: ``ServeEngine`` builds qwen2-0.5b at its published widths in
    bf16 (random weights from a seed) and answers 4 prompts of 128
-   tokens with 32 greedy tokens, twice. A float32 engine at "highest"
-   matmul precision is the reference: its cached decode logits must
-   match the no-cache forward pass over prompt + generated tokens, and
-   the bf16 engine's last-position prefill logits must match its own.
+   tokens with 32 greedy tokens, twice; the second call must compile
+   nothing (``repro.serve.tracing.compiles``). A float32 engine at
+   "highest" matmul precision is the reference: its cached decode
+   logits must match the no-cache forward pass over prompt + generated
+   tokens, and the bf16 engine's last-position prefill logits must
+   match its own.
 3. kernels: the Pallas flash-attention (qwen2-0.5b prefill shape) and
    grouped-matmul (qwen2-moe-a2.7b expert shape) kernels, compiled for
    Mosaic, against the pure-jnp oracles in bf16.
@@ -50,6 +52,7 @@ from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.npu.hw_config import DEFAULT_CORE  # noqa: E402
 from repro.npu.workloads import get_workload  # noqa: E402
 from repro.serve import NPUCluster, PoissonArrivals, ServingSession  # noqa: E402
+from repro.serve import tracing  # noqa: E402
 from repro.serve.engine import ServeEngine  # noqa: E402
 
 SERVE_ARCH = "qwen2-0.5b"
@@ -127,6 +130,7 @@ def check_serve(cfg, batch: int = BATCH, prompt_len: int = PROMPT_LEN,
     t0 = time.perf_counter()
     first = eng.generate(prompts, n_new)
     t_first = time.perf_counter() - t0
+    compiled = tracing.compiles()["compiles"]
     again = eng.generate(prompts, n_new)
     toks = first.tokens
     _check(toks.shape == (batch, n_new), f"tokens shape {toks.shape}")
@@ -134,9 +138,12 @@ def check_serve(cfg, batch: int = BATCH, prompt_len: int = PROMPT_LEN,
            "generated token outside the vocabulary")
     _check(np.array_equal(toks, again.tokens),
            "two identical greedy generate calls disagree")
+    recompiled = tracing.compiles()["compiles"] - compiled
+    _check(recompiled == 0,
+           f"a repeated generate call compiled {recompiled} executables")
     _smoke(f"serve bf16 B={batch} prompt={prompt_len} new={n_new}: first "
-           f"generate incl. compile {t_first:.2f} s; warm prefill "
-           f"{again.prefill_s * 1e3:.2f} ms, decode "
+           f"generate incl. compile {t_first:.2f} s; warm host prefill "
+           f"{again.prefill_s * 1e3:.2f} ms, host decode "
            f"{again.tokens_per_s:.1f} tok/s")
 
     with jax.default_matmul_precision("highest"):

@@ -18,10 +18,16 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.registry import Model, build_model
+from repro.serve import tracing
 
 
 @dataclass
 class GenerationResult:
+    """Tokens of one ``generate`` call, and its host-clock times:
+    ``prefill_s`` from the prompt's put to the end of the prefill's sync
+    (prompt, cache, key and prefill); ``decode_s`` from there to the end
+    of the last ``serve.fetch`` (every token on the host; the last decode
+    step, whose output is discarded, is left running)."""
     tokens: np.ndarray          # (B, n_new) | (B, K, n_new)
     prefill_s: float
     decode_s: float
@@ -41,6 +47,7 @@ class ServeEngine:
         self.params = params
         self._prefill = jax.jit(self.model.prefill)
         self._decode = jax.jit(self.model.decode_step)
+        tracing.listen()
 
     def _sample(self, logits: jax.Array, key, temperature: float):
         # logits: (B, 1, V) or (B, 1, K, V)
@@ -53,48 +60,48 @@ class ServeEngine:
                  temperature: float = 0.0, seed: int = 0
                  ) -> GenerationResult:
         cfg = self.cfg
-        toks = jnp.asarray(prompt_tokens, jnp.int32)
         audio = cfg.family == "audio"
-        B = toks.shape[0]
-        S = toks.shape[-1]
+        shape = np.shape(prompt_tokens)
+        B, S = shape[0], shape[-1]
         assert S + n_new <= self.max_seq, "increase max_seq"
-        cache = self.model.init_cache(B, self.max_seq, self.dtype)
-        key = jax.random.PRNGKey(seed)
+        with tracing.charged():
+            t0 = time.perf_counter()
+            toks = jnp.asarray(prompt_tokens, jnp.int32)
+            cache = self.model.init_cache(B, self.max_seq, self.dtype)
+            key = jax.random.PRNGKey(seed)
+            batch: Dict[str, Any] = {"tokens": toks}
+            if cfg.family == "vlm":
+                batch["patch_embeds"] = jnp.zeros(
+                    (B, cfg.n_patches, cfg.d_model), jnp.float32)
+            logits, cache = self._prefill(self.params, batch, cache)
+            logits.block_until_ready()
+            last = logits[:, -1:]               # (B, 1, V) | (B, 1, K, V)
+            t_prefill = t_fetched = time.perf_counter()
 
-        t0 = time.time()
-        batch: Dict[str, Any] = {"tokens": toks}
-        if cfg.family == "vlm":
-            batch["patch_embeds"] = jnp.zeros(
-                (B, cfg.n_patches, cfg.d_model), jnp.float32)
-        logits, cache = self._prefill(self.params, batch, cache)
-        logits.block_until_ready()
-        t_prefill = time.time() - t0
-
-        t0 = time.time()
-        outs = []
-        last = logits[:, -1:]
-        if audio:
-            pass  # (B, 1, K, V)
-        for i in range(n_new):
-            key, sub = jax.random.split(key)
-            nxt = self._sample(last, sub, temperature)  # (B,1) | (B,1,K)
-            if audio:
-                nxt_in = jnp.moveaxis(nxt, -1, 1)       # (B,K,1)
-            else:
-                nxt_in = nxt
-            outs.append(np.asarray(nxt_in))
-            idx = jnp.asarray(S + i, jnp.int32)
-            last, cache = self._decode(
-                self.params, cache, {"tokens": nxt_in, "cache_index": idx})
-        t_decode = time.time() - t0
+            # every host line of the loop lies in exactly one step span
+            outs = []
+            for i in range(n_new):
+                with tracing.span("sample", step=i):
+                    key, sub = jax.random.split(key)
+                    nxt = self._sample(last, sub, temperature)  # (B,1)|(B,1,K)
+                    nxt_in = jnp.moveaxis(nxt, -1, 1) if audio else nxt
+                with tracing.span("fetch", step=i):
+                    outs.append(np.asarray(nxt_in))
+                    t_fetched = time.perf_counter()
+                with tracing.span("dispatch", step=i):
+                    idx = jnp.asarray(S + i, jnp.int32)
+                    last, cache = self._decode(
+                        self.params, cache,
+                        {"tokens": nxt_in, "cache_index": idx})
         new = np.concatenate(outs, axis=-1)
+        t_decode = t_fetched - t_prefill
         # tokens/s counts generated TIMESTEPS per sequence: an audio
         # model emits K parallel codebook streams per step, which is
         # still one token of audio — new.size would over-count by K
         n_tok = new.shape[0] * new.shape[-1]
         return GenerationResult(
             tokens=new,
-            prefill_s=t_prefill,
+            prefill_s=t_prefill - t0,
             decode_s=t_decode,
             tokens_per_s=n_tok / max(t_decode, 1e-9),
         )
