@@ -68,7 +68,8 @@ class ServeEngine:
             t0 = time.perf_counter()
             toks = jnp.asarray(prompt_tokens, jnp.int32)
             cache = self.model.init_cache(B, self.max_seq, self.dtype)
-            key = jax.random.PRNGKey(seed)
+            # greedy decoding reads no key, so it makes and splits none
+            key = sub = jax.random.PRNGKey(seed) if temperature > 0 else None
             batch: Dict[str, Any] = {"tokens": toks}
             if cfg.family == "vlm":
                 batch["patch_embeds"] = jnp.zeros(
@@ -76,23 +77,33 @@ class ServeEngine:
             logits, cache = self._prefill(self.params, batch, cache)
             logits.block_until_ready()
             last = logits[:, -1:]               # (B, 1, V) | (B, 1, K, V)
-            t_prefill = t_fetched = time.perf_counter()
+            t_prefill = time.perf_counter()
 
-            # every host line of the loop lies in exactly one step span
+            # Each token goes to the next decode step as a device array;
+            # the host copies it one step behind, once that step is
+            # queued, so nothing between a sampler and the next launch
+            # waits for the device. Each step's work lies in exactly one
+            # step span.
             outs = []
             for i in range(n_new):
                 with tracing.span("sample", step=i):
-                    key, sub = jax.random.split(key)
-                    nxt = self._sample(last, sub, temperature)  # (B,1)|(B,1,K)
-                    nxt_in = jnp.moveaxis(nxt, -1, 1) if audio else nxt
-                with tracing.span("fetch", step=i):
-                    outs.append(np.asarray(nxt_in))
-                    t_fetched = time.perf_counter()
+                    if key is not None:
+                        key, sub = jax.random.split(key)
+                    nxt = self._sample(last, sub, temperature)
+                    if audio:
+                        nxt = jnp.moveaxis(nxt, -1, 1)      # (B, K, 1)
+                    outs.append(nxt)
                 with tracing.span("dispatch", step=i):
                     idx = jnp.asarray(S + i, jnp.int32)
                     last, cache = self._decode(
                         self.params, cache,
-                        {"tokens": nxt_in, "cache_index": idx})
+                        {"tokens": nxt, "cache_index": idx})
+                if i:
+                    with tracing.span("fetch", step=i - 1):
+                        outs[i - 1] = np.asarray(outs[i - 1])
+            with tracing.span("fetch", step=n_new - 1):
+                outs[-1] = np.asarray(outs[-1])
+                t_fetched = time.perf_counter()
         new = np.concatenate(outs, axis=-1)
         t_decode = t_fetched - t_prefill
         # tokens/s counts generated TIMESTEPS per sequence: an audio

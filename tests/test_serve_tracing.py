@@ -68,12 +68,16 @@ def engine():
 
 
 def check_step_spans(spans, n_new):
-    """``n_new`` each of sample, fetch and dispatch, numbered by step, in
-    turn and not overlapping, and no other ``serve.*`` span."""
-    assert [s[0][len("serve."):] for s in spans] == (
-        ["sample", "fetch", "dispatch"] * n_new)
-    assert [s[3] for s in spans] == [
-        {"step": i} for i in range(n_new) for _ in range(3)]
+    """``n_new`` each of sample, dispatch and fetch, numbered by the step
+    each works for, in turn and not overlapping, and no other ``serve.*``
+    span. Token i is fetched after step i + 1 is dispatched: sample i,
+    dispatch i, fetch i - 1, and the last token's fetch after the loop."""
+    want = [("sample", 0), ("dispatch", 0)]
+    for i in range(1, n_new):
+        want += [("sample", i), ("dispatch", i), ("fetch", i - 1)]
+    want.append(("fetch", n_new - 1))
+    assert [(s[0][len("serve."):], s[3]) for s in spans] == [
+        (name, {"step": i}) for name, i in want]
     assert all(a[2] <= b[1] for a, b in zip(spans, spans[1:]))
 
 
@@ -100,6 +104,46 @@ def test_tokens_are_the_same_with_the_profiler_on_and_off(engine, tmp_path):
     np.testing.assert_array_equal(on.tokens, off.tokens)
     np.testing.assert_array_equal(off.tokens,
                                   plain_greedy(engine, prompts(), N_NEW))
+
+
+def plain_sampled(eng, toks, n_new, temperature, seed):
+    """The sampled loop over the model's own programs, splitting the key
+    before each token."""
+    cache = eng.model.init_cache(toks.shape[0], eng.max_seq, eng.dtype)
+    logits, cache = jax.jit(eng.model.prefill)(
+        eng.params, {"tokens": jnp.asarray(toks)}, cache)
+    key, last, out = jax.random.PRNGKey(seed), logits[:, -1:], []
+    for i in range(n_new):
+        key, sub = jax.random.split(key)
+        nxt = jax.random.categorical(
+            sub, last / temperature, axis=-1).astype(jnp.int32)
+        out.append(np.asarray(nxt))
+        last, cache = jax.jit(eng.model.decode_step)(
+            eng.params, cache,
+            {"tokens": nxt, "cache_index": jnp.asarray(toks.shape[1] + i,
+                                                       jnp.int32)})
+    return np.concatenate(out, axis=-1)
+
+
+@pytest.mark.parametrize("seed", [0, 3000000019])
+def test_a_sampled_call_keeps_the_key_split_order(engine, seed):
+    got = engine.generate(prompts(2), N_NEW, temperature=0.7, seed=seed)
+    np.testing.assert_array_equal(
+        got.tokens, plain_sampled(engine, prompts(2), N_NEW, 0.7, seed))
+
+
+def test_only_a_sampled_call_splits_keys(engine, monkeypatch):
+    split, n = jax.random.split, []
+
+    def counted(key, *a, **kw):
+        n.append(1)
+        return split(key, *a, **kw)
+
+    monkeypatch.setattr(jax.random, "split", counted)
+    engine.generate(prompts(), N_NEW)
+    assert not n
+    engine.generate(prompts(), N_NEW, temperature=1.0, seed=5)
+    assert len(n) == N_NEW
 
 
 def test_the_timers_cover_the_calls_phases(engine):
