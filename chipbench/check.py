@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import references
+
 CHECK_STREAM = 3
 
 
@@ -60,8 +62,8 @@ def compare(cfg: Mapping[str, Any], weights: Dict[str, Any],
             calls: Sequence[Any], picks: Sequence[Tuple[int, int]],
             max_seq: int, control: bool = False) -> Dict[str, float]:
     """Widest gaps over the sampled requests' served tokens."""
-    mod = f"chipbench.references.{cfg['reference']}"
-    dims = importlib.import_module(mod).Dims.of(cfg)
+    mod = references.module_name(cfg)
+    dims = references.of(cfg).Dims.of(cfg)
     worst = {"served": 0.0, "control": 0.0}
     n_tok = 0
     with jax.default_matmul_precision("highest"):

@@ -13,7 +13,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 
-from chipbench import check, counts, trace as tracing
+from chipbench import check, references, trace as tracing
 from chipbench.spec import Cell
 from chipbench.weights import make_weights
 
@@ -24,7 +24,7 @@ class RunRecord:
     calls: List[Any]              # the window's calls, in order
     setup_s: float
     window_s: float               # host clock, first call start to last end
-    shapes: counts.Shapes
+    shapes: Any                   # the architecture's Shapes
     peaks: Optional[Dict[str, Any]]
     trace: Optional[tracing.Trace]
 
@@ -114,7 +114,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
               "memory_peak_bytes": stats.get("peak_bytes_in_use")}
     rec = RunRecord(calls=calls, setup_s=calls[0].t_start - t0,
                     window_s=calls[-1].t_end - calls[0].t_start,
-                    shapes=counts.Shapes.of(cfg), peaks=peaks, trace=tr)
+                    shapes=references.of(cfg).Shapes.of(cfg),
+                    peaks=peaks, trace=tr)
     if tr is not None:
         device["busy_s"], device["window_s"] = tracing.device_times(tr)
 

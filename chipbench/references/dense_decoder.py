@@ -1,4 +1,5 @@
-"""Plain float32 forward pass of a dense decoder-only transformer.
+"""The dense decoder-only transformer: its weight layout, its plain
+float32 forward pass, and its operations and bytes.
 
 Llama-style blocks as Qwen2 and MiniCPM publish them: RMSNorm before
 attention and before the MLP, rotary position embedding (rotate-half,
@@ -16,17 +17,65 @@ Nothing of the program under test is imported.
 ``fp8=True`` rounds both operands of every matmul to float8_e4m3fn
 (per-tensor scale to the format's largest value) before multiplying in
 float32: the lower-precision control that the check must reject.
+
+``Shapes`` counts what the algorithm needs, never what an implementation
+does, so the roofline and utilisation metrics that divide by it do not
+change when the program changes how it computes:
+
+* a decode step reads every weight once and the *live* KV context of
+  each row (not the cache's full ``max_seq``), and writes one KV entry;
+* prefill is causal attention (half the score matrix) and computes
+  logits at the last position only;
+* an embedding lookup gathers rows and costs no operations; a tied
+  table is read once, as the unembedding.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Mapping, NamedTuple
+from dataclasses import dataclass
+from typing import Any, Dict, Mapping, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = float(jnp.finfo(jnp.float8_e4m3fn).max)
+
+
+def leaf_shapes(cfg: Mapping[str, Any]) -> Dict[str, Tuple[Tuple[int, ...], str]]:
+    """Path -> (shape, init kind) of every leaf, ``L`` layers stacked on
+    the leading axis::
+
+        embed (V, d); final_norm (d,); lm_head (d, V) when untied
+        layers: attn_norm (L, d), wq (L, d, H*hd), wk/wv (L, d, Hkv*hd),
+                bq/bk/bv (L, ...) when attention_bias, wo (L, H*hd, d),
+                mlp_norm (L, d), w_gate/w_up (L, d, F), w_down (L, F, d)
+    """
+    d, L, F, V = (cfg["hidden_size"], cfg["num_hidden_layers"],
+                  cfg["intermediate_size"], cfg["vocab_size"])
+    H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = cfg.get("head_dim") or d // H
+    dq, dkv = H * hd, Hkv * hd
+    out = {
+        "embed": ((V, d), "embed"),
+        "final_norm": ((d,), "norm"),
+        "layers/attn_norm": ((L, d), "norm"),
+        "layers/wq": ((L, d, dq), "dense"),
+        "layers/wk": ((L, d, dkv), "dense"),
+        "layers/wv": ((L, d, dkv), "dense"),
+        "layers/wo": ((L, dq, d), "dense"),
+        "layers/mlp_norm": ((L, d), "norm"),
+        "layers/w_gate": ((L, d, F), "dense"),
+        "layers/w_up": ((L, d, F), "dense"),
+        "layers/w_down": ((L, F, d), "dense"),
+    }
+    if cfg.get("attention_bias"):
+        out.update({"layers/bq": ((L, dq), "bias"),
+                    "layers/bk": ((L, dkv), "bias"),
+                    "layers/bv": ((L, dkv), "bias")})
+    if not cfg["tie_word_embeddings"]:
+        out["lm_head"] = ((d, V), "dense")
+    return out
 
 
 class Dims(NamedTuple):
@@ -126,3 +175,94 @@ def logits(dims: Dims, weights: Dict[str, Any], tokens: jax.Array,
     x = x / dims.logit_divisor
     head = (emb.T if dims.tied else weights["lm_head"]).astype(jnp.float32)
     return _mm("td,dv->tv", x, head, fp8)
+
+
+@dataclass(frozen=True)
+class Shapes:
+    layers: int
+    d: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    d_ff: int
+    vocab: int
+    tied: bool
+    qkv_bias: bool
+    dtype_bytes: int
+
+    @classmethod
+    def of(cls, cfg: Mapping[str, Any]) -> "Shapes":
+        d, h = cfg["hidden_size"], cfg["num_attention_heads"]
+        return cls(
+            layers=cfg["num_hidden_layers"], d=d, heads=h,
+            kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim") or d // h,
+            d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
+            tied=bool(cfg["tie_word_embeddings"]),
+            qkv_bias=bool(cfg.get("attention_bias", False)),
+            dtype_bytes=jnp.dtype(cfg["serve_dtype"]).itemsize)
+
+    # ---- parameters ----------------------------------------------------
+    @property
+    def layer_matmul_params(self) -> int:
+        dq, dkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        return self.d * (dq + 2 * dkv) + dq * self.d + 3 * self.d * self.d_ff
+
+    @property
+    def layer_params(self) -> int:
+        dq, dkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        bias = dq + 2 * dkv if self.qkv_bias else 0
+        return self.layer_matmul_params + bias + 2 * self.d
+
+    @property
+    def embed_params(self) -> int:
+        return self.vocab * self.d
+
+    @property
+    def params(self) -> int:
+        head = 0 if self.tied else self.embed_params
+        return (self.embed_params + head + self.layers * self.layer_params
+                + self.d)
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        return (self.layers * 2 * self.kv_heads * self.head_dim
+                * self.dtype_bytes)
+
+    @property
+    def _weight_bytes_read(self) -> int:
+        """Bytes of the weights one forward pass reads: every layer, the
+        final norm, and the unembedding table (the tied table once)."""
+        return (self.layers * self.layer_params + self.d
+                + self.embed_params) * self.dtype_bytes
+
+    def _attn_flops(self, q_rows: int, keys: float) -> float:
+        # QK^T and PV: 2 matmuls of 2 * head_dim ops per (query, key, head)
+        return 4.0 * self.layers * self.heads * self.head_dim * q_rows * keys
+
+    # ---- one decode step -------------------------------------------------
+    def decode_flops(self, batch: int, ctx: int) -> float:
+        """One new token per row, attending over ``ctx`` positions (the
+        new one included)."""
+        per_row = 2.0 * (self.layers * self.layer_matmul_params
+                         + self.embed_params)
+        return batch * per_row + self._attn_flops(batch, ctx)
+
+    def decode_bytes(self, batch: int, ctx: int) -> float:
+        return (self._weight_bytes_read
+                + batch * ctx * self.kv_bytes_per_token
+                + batch * self.d * self.dtype_bytes)
+
+    # ---- prefill -------------------------------------------------------
+    def prefill_flops(self, batch: int, seq: int) -> float:
+        tokens = batch * seq
+        causal_keys = (seq + 1) / 2.0      # mean keys per query, causal
+        return (2.0 * tokens * self.layers * self.layer_matmul_params
+                + self._attn_flops(tokens, causal_keys)
+                + 2.0 * batch * self.d * self.vocab)
+
+    def prefill_bytes(self, batch: int, seq: int) -> float:
+        tokens = batch * seq
+        return (self._weight_bytes_read
+                + tokens * self.kv_bytes_per_token
+                + tokens * self.d * self.dtype_bytes)

@@ -6,14 +6,15 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import counts
+from chipbench import counts, references
 
 ROOT = Path(__file__).resolve().parents[2]
 
 
 def shapes(name):
-    return counts.Shapes.of(json.loads(
-        (ROOT / "chipbench" / "configs" / f"{name}.json").read_text()))
+    cfg = json.loads(
+        (ROOT / "chipbench" / "configs" / f"{name}.json").read_text())
+    return references.of(cfg).Shapes.of(cfg)
 
 
 @pytest.mark.parametrize("name, params, kv_bytes", [

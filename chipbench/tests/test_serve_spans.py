@@ -92,7 +92,12 @@ def test_spans_are_read_from_the_profilers_file_of_the_same_trace(
     tr = trace.load(tmp_path / "t")
     monkeypatch.setattr(_serve_spans, "TRACE_DIR", tmp_path / "t")
     names = [s[0] for s in _serve_spans.program_spans(run_of(tr))]
-    assert names == ["serve.sample", "serve.fetch", "serve.dispatch"] * 3
+    # token i is fetched after step i + 1 is dispatched; the last after
+    # the loop
+    assert names == ["serve.sample", "serve.dispatch",
+                     "serve.sample", "serve.dispatch", "serve.fetch",
+                     "serve.sample", "serve.dispatch", "serve.fetch",
+                     "serve.fetch"]
     # another trace's file is not read
     assert _serve_spans.program_spans(run_of(hand_trace())) == []
 

@@ -5,7 +5,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from chipbench import counts, trace
+from chipbench import counts, references, trace
 from chipbench.metrics import (decode_gap_ms, decode_step_ms, idle_share,
                                prefill_ms)
 
@@ -75,7 +75,7 @@ def test_roofline_never_counts_more_than_the_chip_can_do():
     from pathlib import Path
     cfg = json.loads((Path(__file__).resolve().parents[1] / "configs"
                       / "qwen2-0.5b.json").read_text())
-    sh = counts.Shapes.of(cfg)
+    sh = references.of(cfg).Shapes.of(cfg)
     peaks = counts.peaks_for("TPU v5 lite")
     call = SimpleNamespace(batch=32, prompt_len=128, n_new=2)
     t = [counts.min_seconds(sh.decode_flops(32, c), sh.decode_bytes(32, c),
