@@ -16,7 +16,7 @@ One process, no child processes, no options. Phases, in order:
    tokens, and the bf16 engine's last-position prefill logits must
    match its own.
 3. kernels: the Pallas flash-attention (qwen2-0.5b prefill shape) and
-   grouped-matmul (qwen2-moe-a2.7b expert shape) kernels, compiled for
+   ragged grouped-matmul (qwen2-moe-a2.7b expert shape) kernels, compiled for
    Mosaic, against the pure-jnp oracles in bf16.
 4. fleet sweep: ``sim_jax.sweep_collocations`` on the device against
    the discrete-event simulator and against the same call on the CPU.
@@ -47,7 +47,7 @@ from repro.core import (TenantSpec, VNPUConfig, VNPUManager,  # noqa: E402
 from repro.core.sim_jax import sweep_collocations  # noqa: E402
 from repro.core.simulator import Simulator  # noqa: E402
 from repro.kernels import ops  # noqa: E402
-from repro.kernels.ref import gqa_attention_ref, grouped_matmul_ref  # noqa: E402
+from repro.kernels.ref import gqa_attention_ref, ragged_matmul_ref  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.npu.hw_config import DEFAULT_CORE  # noqa: E402
 from repro.npu.workloads import get_workload  # noqa: E402
@@ -58,7 +58,7 @@ from repro.serve.engine import ServeEngine  # noqa: E402
 SERVE_ARCH = "qwen2-0.5b"
 MOE_ARCH = "qwen2-moe-a2.7b"
 BATCH, PROMPT_LEN, N_NEW, MAX_SEQ = 4, 128, 32, 512
-MOE_CAPACITY = 128
+MOE_ROWS = 16 * 128 * 4      # a (16, 128) prefill, top-4
 
 # Limits are on max|x - ref| / max(1, max|ref|) over the compared logits.
 # f32 at "highest" precision differs between the cached and the no-cache
@@ -202,20 +202,25 @@ def check_kernels(seed: int = 0) -> None:
         np.asarray(gqa_attention_ref(q, kk, vv), np.float32),
         **BF16_KERNEL_TOL)
 
-    x = jax.random.normal(
-        k[3], (moe.n_experts, MOE_CAPACITY, moe.d_model), bf16)
+    x = jax.random.normal(k[3], (MOE_ROWS, moe.d_model), bf16)
     w = jax.random.normal(
         k[4], (moe.n_experts, moe.d_model, moe.d_expert), bf16)
+    rng = np.random.default_rng(seed)
+    sizes = rng.multinomial(MOE_ROWS, rng.dirichlet(np.ones(moe.n_experts)))
+    sizes[::7] = 0                       # experts that got no rows
+    sizes[1] += MOE_ROWS - sizes.sum()
+    sizes = jnp.asarray(sizes, jnp.int32)
+    gmm = jax.jit(ops.gmm)
     t0 = time.perf_counter()
-    _compiled_kernel(ops.grouped_matmul, x, w)
-    out = ops.grouped_matmul(x, w)
+    _compiled_kernel(gmm, x, w, sizes)
+    out = gmm(x, w, sizes)
     out.block_until_ready()
-    _smoke(f"grouped_matmul {tuple(x.shape)}x{tuple(w.shape)}: "
+    _smoke(f"ragged gmm {tuple(x.shape)}x{tuple(w.shape)}: "
            f"compile+first call {time.perf_counter() - t0:.2f} s")
-    _on_default_platform(out, "grouped_matmul")
+    _on_default_platform(out, "gmm")
     np.testing.assert_allclose(
         np.asarray(out, np.float32),
-        np.asarray(grouped_matmul_ref(x, w), np.float32),
+        np.asarray(ragged_matmul_ref(x, w, sizes), np.float32),
         **BF16_KERNEL_TOL)
 
 

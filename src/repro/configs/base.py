@@ -40,6 +40,8 @@ class ModelConfig:
     n_experts_per_tok: int = 0   # top-k
     n_shared_experts: int = 0
     d_expert: int = 0            # per routed expert ffn dim (fine-grained)
+    moe_norm_topk: bool = True   # renormalise the top-k gates to sum to 1
+    shared_expert_gate: bool = False  # shared expert x sigmoid(x @ w)
 
     # ---- SSM (Mamba2 / SSD) ----
     ssm_state: int = 0
@@ -129,6 +131,8 @@ class ModelConfig:
             d_e = self.d_expert or self.d_ff
             routed = self.n_experts * 3 * d * d_e
             shared = self.n_shared_experts * 3 * d * d_e
+            if self.n_shared_experts and self.shared_expert_gate:
+                shared += d
             router = d * self.n_experts
             per_layer = attn + routed + shared + router + 2 * d
         elif self.family == "ssm" and self.xlstm_pattern:

@@ -278,41 +278,19 @@ def sanitize_spec(shape: Tuple[int, ...], spec: P, mesh: Mesh) -> P:
     return P(*out)
 
 
-def maybe_constrain(x, *entries):
-    """with_sharding_constraint that degrades to identity when no
-    ambient mesh (or none with the named axes) is present — layers can
-    call it unconditionally; single-device tests are unaffected."""
-    try:
-        mesh = None
-        try:  # legacy `with mesh:` context (what pjit tracing sees)
-            from jax._src import mesh as _mesh_lib
+def ambient_mesh():
+    """The mesh a program is being traced under (``with mesh:`` or
+    ``jax.sharding.use_mesh``), or None on a single device: layers that
+    must place work across devices themselves, as a Pallas kernel
+    needs, ask it."""
+    from jax._src import mesh as _mesh_lib
 
-            env = _mesh_lib.thread_resources.env.physical_mesh
-            if env is not None and not env.empty:
-                mesh = env
-        except Exception:
-            pass
-        if mesh is None:
-            mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        names = set(mesh.axis_names)
-        cleaned = []
-        for e in entries:
-            if e is None:
-                cleaned.append(None)
-                continue
-            axes = tuple(a for a in (e if isinstance(e, tuple) else (e,))
-                         if a in names)
-            if not axes:
-                cleaned.append(None)
-            elif len(axes) == 1:
-                cleaned.append(axes[0])
-            else:
-                cleaned.append(axes)
-        return jax.lax.with_sharding_constraint(x, P(*cleaned))
-    except Exception:
-        return x
+    mesh = _mesh_lib.thread_resources.env.physical_mesh
+    if mesh is None or mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1:
+        return None
+    return mesh
 
 
 def shardings_for(shapes_tree, spec_tree, mesh: Mesh):

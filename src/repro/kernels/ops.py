@@ -8,6 +8,7 @@ multiples is handled here so callers pass natural shapes.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,18 +51,51 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return o.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("bc", "bd", "bf"))
-def grouped_matmul(x: jax.Array, w: jax.Array, bc: int = 128,
-                   bd: int = 512, bf: int = 128) -> jax.Array:
-    """x: (E, C, d) @ w: (E, d, f) -> (E, C, f), padding-safe."""
-    e, c, d = x.shape
-    f = w.shape[2]
-    bc_, bd_, bf_ = min(bc, c), min(bd, d), min(bf, f)
-    pc, pd, pf = (-c) % bc_, (-d) % bd_, (-f) % bf_
-    if pc or pd:
-        x = jnp.pad(x, ((0, 0), (0, pc), (0, pd)))
-    if pd or pf:
-        w = jnp.pad(w, ((0, 0), (0, pd), (0, pf)))
-    o = moe_gmm.grouped_matmul(x, w, bc=bc_, bd=bd_, bf=bf_,
-                               interpret=_interpret())
-    return o[:, :c, :f]
+TM = 256     # row tile of the ragged matmul, at most
+
+
+def _row_tile(m: int) -> int:
+    return min(TM, -(-m // 16) * 16)
+
+
+@jax.jit
+def _gmm_fwd(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+             layer: jax.Array) -> jax.Array:
+    m = x.shape[0]
+    tm = _row_tile(m)
+    pad = (-m) % tm
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    out = moe_gmm.gmm(x, w, group_sizes.astype(jnp.int32), layer, tm=tm,
+                      interpret=_interpret())
+    return out[:m]
+
+
+def gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+        layer: Optional[jax.Array] = None) -> jax.Array:
+    """Ragged grouped matmul: x (M, d) sorted by group, group_sizes (E,)
+    summing to M, and the experts w (E, d, f), or every layer's
+    (L, E, d, f) with the ``layer`` to use -> (M, f). The backward pass
+    is ``jax.lax.ragged_dot``'s, the same product."""
+    if layer is None:
+        w, layer = w[None], 0
+    return _gmm(x, w, group_sizes, jnp.asarray(layer, jnp.int32))
+
+
+@jax.custom_vjp
+def _gmm(x, w, group_sizes, layer):
+    return _gmm_fwd(x, w, group_sizes, layer)
+
+
+def _gmm_vjp_fwd(x, w, group_sizes, layer):
+    return _gmm_fwd(x, w, group_sizes, layer), (x, w, group_sizes, layer)
+
+
+def _gmm_vjp_bwd(res, g):
+    x, w, group_sizes, layer = res
+    _, vjp = jax.vjp(
+        lambda x_, w_: jax.lax.ragged_dot(x_, w_[layer], group_sizes), x, w)
+    return (*vjp(g.astype(x.dtype)), None, None)
+
+
+_gmm.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)

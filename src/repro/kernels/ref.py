@@ -5,6 +5,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -35,8 +36,15 @@ def gqa_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     return o.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
 
 
-def grouped_matmul_ref(x: jax.Array, w: jax.Array) -> jax.Array:
-    """x: (E, C, d); w: (E, d, f) -> (E, C, f). fp32 accumulation."""
-    out = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
-                     w.astype(jnp.float32))
+def ragged_matmul_ref(x: jax.Array, w: jax.Array,
+                      group_sizes) -> jax.Array:
+    """x: (M, d) sorted by group; w: (E, d, f); group_sizes: (E,), known
+    on the host -> (M, f), one plain matmul per group in fp32. Rows past
+    the last group are zero."""
+    out = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    start = 0
+    for g, n in enumerate(np.asarray(group_sizes).tolist()):
+        rows = x[start:start + n].astype(jnp.float32)
+        out = out.at[start:start + n].set(rows @ w[g].astype(jnp.float32))
+        start += n
     return out.astype(x.dtype)

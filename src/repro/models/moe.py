@@ -1,23 +1,37 @@
-"""Mixture-of-Experts layer: top-k routing with GShard-style capacity
-dispatch lowered to ONE batched expert GEMM.
+"""Mixture-of-Experts layer: dropless top-k routing over a ragged
+grouped matmul.
 
-Dispatch = scatter tokens into (E, C, d) slot buffers (dropped tokens
-fall through on the residual); experts run as a single
-``einsum('ecd,edf->ecf')`` so the compiler sees a dense grouped GEMM —
-shardable either on the expert axis (EP: dbrx, 16 experts / 16-way
-`model`) or on the ffn axis (TP: qwen2-moe, 60 experts). Combine =
-gather + gate-weighted sum. Fully differentiable (gate grads flow
-through the top-k values; index grads are zero as usual).
+Every token's top-k (token, expert) assignments are sorted by expert
+and run through ``kernels.ops.gmm`` (gate/up, then down), whose groups
+are the experts' row counts: every assignment is computed at any batch
+and length, and an expert that got no rows reads no weights. The rows
+are then unsorted and combined with their gates. The gates are the
+softmax probabilities of the chosen experts, renormalised to sum to one
+only where ``cfg.moe_norm_topk``. A shared expert (``n_shared_experts``
+x ``d_expert`` wide) is added to every token, scaled by
+``sigmoid(x @ shared_gate)`` where ``cfg.shared_expert_gate``.
+Differentiable through the gates and the matmuls (the kernel's backward
+pass is ``jax.lax.ragged_dot``'s).
+
+Under a mesh the expert FFN runs in a ``shard_map``, since a Pallas
+kernel is not partitioned for it: each data shard sorts its own tokens,
+and the experts are split over ``model`` as ``cfg.moe_shard`` and
+``distributed.sharding.param_specs`` lay them out, by expert or by ffn
+column; the shards' partial sums are added with one ``psum``.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import MODEL_AX, ambient_mesh, dp_axes
+from repro.kernels import ops
 from repro.models.layers import _dense_init, mlp_apply, mlp_init
 
 Params = Dict[str, Any]
@@ -27,8 +41,7 @@ def moe_init(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
     d = cfg.d_model
     d_e = cfg.d_expert or cfg.d_ff
     E = cfg.n_experts
-    ks = jax.random.split(key, 5)
-    scale = 1.0 / math.sqrt(d)
+    ks = jax.random.split(key, 6)
 
     def experts(k, d_in, d_out):
         return (jax.random.normal(k, (E, d_in, d_out), jnp.float32)
@@ -44,112 +57,114 @@ def moe_init(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
         shared_cfg = cfg.replace(mlp_gated=True)
         p["shared"] = mlp_init(shared_cfg, ks[4], dtype,
                                d_ff=cfg.n_shared_experts * d_e)
+        if cfg.shared_expert_gate:
+            p["shared_gate"] = _dense_init(ks[5], d, 1, dtype)
     return p
 
 
-def capacity(cfg: ModelConfig, n_tokens: int, factor: float = 1.25) -> int:
-    if n_tokens <= 128:
-        # dropless for tiny groups (decode steps, smoke tests): the
-        # worst-case buffer is E x (n_tokens*k) x d — negligible — and
-        # decode/prefill logits stay bit-consistent (no token drops).
-        c = n_tokens * cfg.n_experts_per_tok
-        return max(8, -(-c // 8) * 8)
-    c = math.ceil(n_tokens * cfg.n_experts_per_tok / cfg.n_experts * factor)
-    return max(8, -(-c // 8) * 8)  # pad to 8 for tiling friendliness
+def group_sizes(experts: jax.Array, n: int) -> jax.Array:
+    """Rows of each of ``n`` experts among the assignments ``experts``."""
+    return jnp.sum(experts[None, :] == jnp.arange(n)[:, None], axis=1,
+                   dtype=jnp.int32)
 
 
-def _n_groups(B: int, cap: int = 64) -> int:
-    """Largest power of two <= cap that divides the batch — groups
-    align with (and subdivide) the data-parallel batch shards."""
-    g = 1
-    while g * 2 <= min(cap, B) and B % (g * 2) == 0:
-        g *= 2
-    return g
+def _expert_ffn(xt: jax.Array, gates: jax.Array, idx: jax.Array,
+                w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                layer: Optional[jax.Array], n_experts: int,
+                axis: Optional[str] = None) -> jax.Array:
+    """Each token's chosen experts' SwiGLU outputs, summed with their
+    gates: (T, d) float32. ``axis``: inside a ``shard_map``, the mesh
+    axis the experts are split over; the result is then this shard's
+    part of the sum, added over ``axis`` here."""
+    T, k = idx.shape
+    m = T * k
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = group_sizes(flat, n_experts)
+    src = order // k                                         # token per row
+    back = jnp.argsort(order)                                # row per pick
+    local = w_gate.shape[-3]
+    if local < n_experts:
+        # this shard holds experts [first, first + local): rotate their
+        # rows to the front, where the kernel's groups start
+        first = jax.lax.axis_index(axis) * local
+        start = jnp.sum(jnp.where(jnp.arange(n_experts) < first, sizes, 0))
+        sizes = jax.lax.dynamic_slice(sizes, (first,), (local,))
+        src = jnp.take(src, (jnp.arange(m) + start) % m)
+        back = (back - start) % m
+    xs = jnp.take(xt, src, axis=0)                           # (T*k, d)
+    h = (jax.nn.silu(ops.gmm(xs, w_gate, sizes, layer))
+         * ops.gmm(xs, w_up, sizes, layer))
+    ys = ops.gmm(h, w_down, sizes, layer)                    # sorted rows
+    if local < n_experts:      # rows of other shards' experts: unwritten
+        ys = jnp.where((jnp.arange(m) < jnp.sum(sizes))[:, None], ys, 0)
+    y = jnp.take(ys, back, axis=0).reshape(T, k, -1)
+    y = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), gates)
+    return y if axis is None else jax.lax.psum(y, axis)
+
+
+def _sharded_expert_ffn(mesh, cfg: ModelConfig, xt, gates, idx, w_gate,
+                        w_up, w_down, layer):
+    """``_expert_ffn`` in a ``shard_map`` over ``mesh``: tokens split
+    over the data axes, the experts over ``model`` as the parameters
+    are laid out (``param_specs``)."""
+    names = set(mesh.axis_names)
+    dp = tuple(a for a in ("pod", "data") if a in names)
+    n_dp = math.prod(mesh.shape[a] for a in dp)
+    tok = P(dp_axes(mesh) if dp and xt.shape[0] % n_dp == 0 else None, None)
+    n_model = mesh.shape[MODEL_AX] if MODEL_AX in names else 1
+    lead = (None,) * (w_gate.ndim - 3)           # the layer axis, if stacked
+    f = w_gate.shape[-1]
+    if n_model > 1 and cfg.moe_shard == "expert" \
+            and cfg.n_experts % n_model == 0:
+        axis = MODEL_AX
+        wg = wd = P(*lead, MODEL_AX, None, None)
+    elif n_model > 1 and f % n_model == 0:
+        axis = MODEL_AX
+        wg, wd = P(*lead, None, None, MODEL_AX), P(*lead, None, MODEL_AX, None)
+    else:
+        axis = None
+        wg = wd = P(*lead, None, None, None)
+    fn = functools.partial(_expert_ffn, n_experts=cfg.n_experts, axis=axis)
+    args = (xt, gates, idx, w_gate, w_up, w_down)
+    specs = (tok, tok, tok, wg, wg, wd)
+    if layer is None:
+        fn = functools.partial(fn, layer=None)
+    else:
+        args, specs = args + (layer,), specs + (P(),)
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs, out_specs=tok,
+                         check_vma=False)(*args)
 
 
 def moe_apply(p: Params, cfg: ModelConfig, x: jax.Array,
-              capacity_factor: float = 1.25) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output, aux_loss). x: (B, S, d).
-
-    GShard-style GROUPED dispatch: tokens are ranked against a
-    per-group capacity, so the rank prefix-sum runs along the token
-    axis WITHIN a group while the group axis stays batch-sharded — no
-    cross-shard prefix scans or global scatters (the baseline
-    one-hot-cumsum over all B*S*k assignments made qwen2-moe train_4k
-    the most collective-bound dry-run cell; see EXPERIMENTS.md §Perf).
-    """
+              layer: Optional[jax.Array] = None
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (output, aux_loss, experts that got at least one row).
+    x: (B, S, d). The routed experts are this layer's (E, d, f), or with
+    ``layer`` every layer's (L, E, d, f), stacked."""
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.n_experts_per_tok
-    G = _n_groups(B)
-    T = B * S
-    Tg = T // G
-    C = capacity(cfg, Tg, capacity_factor)
-    xg = x.reshape(G, Tg, d)
+    xt = x.reshape(B * S, d)
+    probs = jax.nn.softmax((xt @ p["router"]).astype(jnp.float32), axis=-1)
+    gates, idx = jax.lax.top_k(probs, k)                     # (T, k)
+    if cfg.moe_norm_topk:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    # load-balancing auxiliary loss (Switch-style), by scatter-add: a
+    # (T, E) one-hot is O(T * E) bytes
+    density = jnp.zeros((E,), jnp.float32).at[idx[:, 0]].add(1.0)
+    aux = E * jnp.sum(density / (B * S) * jnp.mean(probs, axis=0))
+    routed = jnp.sum(group_sizes(idx.reshape(-1), E) > 0).astype(jnp.int32)
 
-    logits = (xg @ p["router"]).astype(jnp.float32)         # (G, Tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)          # (G, Tg, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    experts = (p["w_gate"], p["w_up"], p["w_down"], layer)
+    mesh = ambient_mesh()
+    if mesh is None:
+        y = _expert_ffn(xt, gates, idx, *experts, n_experts=E)
+    else:
+        y = _sharded_expert_ffn(mesh, cfg, xt, gates, idx, *experts)
 
-    # load-balancing auxiliary loss (Switch-style). Scatter-add, not
-    # one-hot: a (G,Tg,E) one-hot here is O(T*E) bytes (250GB at
-    # train_4k scale).
-    density = jnp.zeros((E,), jnp.float32).at[
-        expert_idx[..., 0].reshape(-1)].add(1.0) / (G * Tg)
-    mean_prob = jnp.mean(probs, axis=(0, 1))
-    aux_loss = E * jnp.sum(density * mean_prob)
-
-    # --- dispatch: rank of each assignment within (group, expert) ---
-    # Sort-based ranking. The GShard one-hot-cumsum materializes a
-    # (G, A, E) int tensor (~1 TB at train_4k) and drags TBs of
-    # all-reduce through the backward pass — the dominant collective
-    # term of the baseline dry-run. Two argsorts + a searchsorted on
-    # (G, A) int32 (~16 MB) computes the same ranks.
-    A = Tg * k
-    flat_e = expert_idx.reshape(G, A)                        # (G, A)
-    order = jnp.argsort(flat_e, axis=1, stable=True)
-    sorted_e = jnp.take_along_axis(flat_e, order, axis=1)
-    pos = jnp.arange(A, dtype=jnp.int32)[None, :]
-    first = jax.vmap(
-        lambda se: jnp.searchsorted(se, se, side="left"))(sorted_e)
-    ranks_sorted = pos - first
-    inv = jnp.argsort(order, axis=1)
-    ranks = jnp.take_along_axis(ranks_sorted, inv, axis=1)
-    ranks = jax.lax.stop_gradient(ranks)
-    keep = ranks < C
-    slot = jnp.where(keep, flat_e * C + ranks, E * C)        # E*C = drop
-
-    x_rep = jnp.repeat(xg, k, axis=1)                        # (G, A, d)
-    buf = jnp.zeros((G, E * C, d), x.dtype)
-    buf = jax.vmap(
-        lambda b, s, v: b.at[s].add(v, mode="drop"))(
-            buf, slot, jnp.where(keep[..., None], x_rep, 0))
-    # GSPMD cannot propagate batch sharding through the scatter — left
-    # alone it replicates the dispatch buffers across `data` and
-    # all-reduces the expert outputs (TBs/step at train_4k). Pin the
-    # group dim to the DP axes explicitly.
-    from repro.distributed.sharding import maybe_constrain
-
-    buf = maybe_constrain(buf, ("pod", "data"), None, None)
-    h = buf.reshape(G, E, C, d)
-
-    # --- grouped expert GEMMs (one batched einsum each) ---
-    g_ = jax.nn.silu(jnp.einsum("gecd,edf->gecf", h, p["w_gate"]))
-    u = jnp.einsum("gecd,edf->gecf", h, p["w_up"])
-    y_e = jnp.einsum("gecf,efd->gecd", g_ * u, p["w_down"])
-    y_e = maybe_constrain(y_e, ("pod", "data"), None, None, None)
-
-    # --- combine: gather + gate-weighted sum over the k slots ---
-    y_flat = y_e.reshape(G, E * C, d)
-    y_tok = jnp.take_along_axis(
-        y_flat, jnp.minimum(slot, E * C - 1)[..., None], axis=1)
-    y_tok = jnp.where(keep[..., None], y_tok, 0)
-    y_tok = y_tok.reshape(G, Tg, k, d)
-    gates = gate_vals.astype(x.dtype)[..., None]             # (G, Tg, k, 1)
-    y = jnp.sum(y_tok * gates, axis=2)
-
-    y = y.reshape(B, S, d)
     if "shared" in p:
-        shared_cfg = cfg.replace(mlp_gated=True)
-        y = y + mlp_apply(p["shared"], shared_cfg, x)
-    return y, aux_loss
+        s = mlp_apply(p["shared"], cfg.replace(mlp_gated=True), xt)
+        if cfg.shared_expert_gate:
+            s = s * jax.nn.sigmoid(xt @ p["shared_gate"])
+        y = y + s.astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, d), aux, routed

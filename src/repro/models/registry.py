@@ -6,6 +6,7 @@ serve layers program against.
   loss, metrics = model.loss(params, batch)              # training
   logits, cache = model.prefill(params, batch)           # serving
   logits, cache = model.decode_step(params, cache, batch)
+  model.routed(cache)        # MoE: experts routed to, on the device
 
 Batches are dicts:
   train:   {"tokens": (B,S) | (B,K,S), "targets": same, [patch_embeds]}
@@ -62,10 +63,10 @@ class Model:
         c = self.cfg
         tokens = batch["tokens"]
         if c.family in ("dense", "moe", "vlm", "audio"):
-            lg, _, aux = transformer.forward(
+            lg, _, stats = transformer.forward(
                 c, params, tokens, patch_embeds=batch.get("patch_embeds"),
                 remat=self.remat, use_flash=self.use_flash)
-            return lg, aux
+            return lg, stats["aux"]
         if c.family == "ssm" and c.xlstm_pattern:
             lg, _, aux = stacks.xlstm_forward(c, params, tokens,
                                               remat=self.remat)
@@ -93,9 +94,16 @@ class Model:
         return total, {"ce": ce, "aux": aux}
 
     # ---------------- serving ----------------
+    # An MoE model's serving state is (k, v, routed): ``routed`` is an
+    # int32 scalar that every prefill and decode step adds its experts
+    # routed to (summed over layers) to, so the count stays on the
+    # device until a caller fetches it (``routed``).
     def init_cache(self, batch: int, max_seq: int, dtype=jnp.float32):
         c = self.cfg
-        if c.family in ("dense", "moe", "vlm", "audio"):
+        if c.family == "moe":
+            return (*transformer.init_cache(c, batch, max_seq, dtype),
+                    jnp.zeros((), jnp.int32))
+        if c.family in ("dense", "vlm", "audio"):
             return transformer.init_cache(c, batch, max_seq, dtype)
         if c.family == "ssm" and c.xlstm_pattern:
             return stacks.xlstm_state(c, batch, dtype)
@@ -105,7 +113,12 @@ class Model:
         """Full-sequence forward that fills the cache/state."""
         c = self.cfg
         tokens = batch["tokens"]
-        if c.family in ("dense", "moe", "vlm", "audio"):
+        if c.family == "moe":
+            lg, kv, stats = transformer.forward(
+                c, params, tokens, cache=cache[:2],
+                use_flash=self.use_flash, last_only=self.prefill_last_only)
+            return lg, (*kv, cache[2] + jnp.sum(stats["experts"]))
+        if c.family in ("dense", "vlm", "audio"):
             lg, cache, _ = transformer.forward(
                 c, params, tokens, cache=cache,
                 patch_embeds=batch.get("patch_embeds"),
@@ -125,7 +138,11 @@ class Model:
         c = self.cfg
         tokens = batch["tokens"]
         idx = batch["cache_index"]
-        if c.family in ("dense", "moe", "vlm", "audio"):
+        if c.family == "moe":
+            lg, kv, stats = transformer.forward(
+                c, params, tokens, cache=cache[:2], cache_index=idx)
+            return lg, (*kv, cache[2] + jnp.sum(stats["experts"]))
+        if c.family in ("dense", "vlm", "audio"):
             lg, cache, _ = transformer.forward(
                 c, params, tokens, cache=cache, cache_index=idx)
             return lg, cache
@@ -136,6 +153,11 @@ class Model:
         lg, st, _ = stacks.zamba2_forward(c, params, tokens, state=cache,
                                           cache_index=idx, decode=True)
         return lg, st
+
+    def routed(self, cache) -> Optional[jax.Array]:
+        """Experts routed to since ``init_cache``, summed over layers and
+        runs, as a device scalar; None for a model without routing."""
+        return cache[2] if self.cfg.family == "moe" else None
 
     # ---------------- shape builders (dry-run / data pipeline) -------
     def batch_shapes(self, kind: str, batch: int, seq: int

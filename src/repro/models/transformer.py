@@ -101,7 +101,13 @@ def _layer_apply(
     cache_l: Optional[Tuple[jax.Array, jax.Array]],
     cache_index: Optional[jax.Array],
     use_flash: bool,
-) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]], jax.Array]:
+    experts: Optional[Params] = None,
+) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]], jax.Array,
+           Optional[jax.Array]]:
+    """(x, new cache, aux loss, experts routed to: MoE layers only).
+    ``experts``: every layer's routed experts, stacked, for an MoE layer
+    whose ``p_l["moe"]["layer"]`` says which is its own; without it an
+    MoE layer's experts are its own slice in ``p_l["moe"]``."""
     h = L.rmsnorm(p_l["attn_norm"], x, cfg.norm_eps)
     attn_out, new_cache = L.attention_apply(
         p_l["attn"], cfg, h, positions, cache=cache_l,
@@ -109,11 +115,16 @@ def _layer_apply(
     x = x + attn_out
     h = L.rmsnorm(p_l["mlp_norm"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
+    routed = None
     if cfg.family == "moe":
-        out, aux = moe_apply(p_l["moe"], cfg, h)
+        m, layer = p_l["moe"], None
+        if experts is not None:
+            m = dict(m, **experts)
+            layer = m.pop("layer")
+        out, aux, routed = moe_apply(m, cfg, h, layer)
     else:
         out = L.mlp_apply(p_l["mlp"], cfg, h)
-    return x + out, new_cache, aux
+    return x + out, new_cache, aux, routed
 
 
 # ----------------------------------------------------------------------
@@ -127,8 +138,10 @@ def forward(
     remat: bool = False,
     use_flash: bool = False,
     last_only: bool = False,
-) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Shared trunk. Returns (logits, new_cache, aux_loss)."""
+) -> Tuple[jax.Array, Optional[Params], Dict[str, jax.Array]]:
+    """Shared trunk. Returns (logits, new_cache, stats): ``stats["aux"]``
+    is the auxiliary loss; an MoE model adds ``stats["experts"]``, (L,)
+    int32, how many experts each layer routed at least one row to."""
     x = _embed_tokens(p, cfg, tokens)
     if cfg.family == "vlm" and patch_embeds is not None:
         x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
@@ -138,36 +151,51 @@ def forward(
     else:
         positions = jnp.arange(S, dtype=jnp.int32)[None, :].repeat(B, 0)
 
+    layers, stacked = p["layers"], None
+    if cfg.family == "moe" and cache is not None:
+        # serving: the routed experts stay stacked over the layers,
+        # outside the scan, and the expert kernel reads its layer's
+        # blocks in place (a scanned slice is a copy of every expert of
+        # the layer, each step). Training scans the per-layer slices, so
+        # each layer's expert gradient is its own slice.
+        moe = dict(layers["moe"], layer=jnp.arange(cfg.n_layers))
+        stacked = {k: moe.pop(k) for k in ("w_gate", "w_up", "w_down")}
+        layers = dict(layers, moe=moe)
+
     def body_nocache(carry, p_l):
         xc, aux = carry
-        xc, _, aux_l = _layer_apply(
-            cfg, p_l, xc, positions, None, None, use_flash)
-        return (xc, aux + aux_l), None
+        xc, _, aux_l, routed = _layer_apply(
+            cfg, p_l, xc, positions, None, None, use_flash, stacked)
+        return (xc, aux + aux_l), routed
 
     def body_cache(carry, xs):
         xc, aux = carry
         p_l, cache_l = xs
-        xc, new_cache_l, aux_l = _layer_apply(
-            cfg, p_l, xc, positions, cache_l, cache_index, use_flash)
-        return (xc, aux + aux_l), new_cache_l
+        xc, new_cache_l, aux_l, routed = _layer_apply(
+            cfg, p_l, xc, positions, cache_l, cache_index, use_flash,
+            stacked)
+        return (xc, aux + aux_l), (new_cache_l, routed)
 
     if cache is None:
         body_fn = jax.checkpoint(body_nocache) if remat else body_nocache
-        (x, aux), _ = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)), p["layers"])
+        (x, aux), routed = jax.lax.scan(
+            body_fn, (x, jnp.zeros((), jnp.float32)), layers)
         new_cache = None
     else:
         body_fn = jax.checkpoint(body_cache) if remat else body_cache
-        (x, aux), new_cache = jax.lax.scan(
+        (x, aux), (new_cache, routed) = jax.lax.scan(
             body_fn, (x, jnp.zeros((), jnp.float32)),
-            (p["layers"], cache))
+            (layers, cache))
     if last_only:
         # serving prefill wants next-token logits only: slicing BEFORE
         # the unembed avoids materializing (B, S, V) logits.
         x = x[:, -1:]
     x = L.rmsnorm(p["final_norm"], x, cfg.norm_eps)
     logits = _unembed(p, cfg, x)
-    return logits, new_cache, aux
+    stats = {"aux": aux}
+    if routed is not None:
+        stats["experts"] = routed
+    return logits, new_cache, stats
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,

@@ -76,6 +76,7 @@ class ServeEngine:
                     (B, cfg.n_patches, cfg.d_model), jnp.float32)
             logits, cache = self._prefill(self.params, batch, cache)
             logits.block_until_ready()
+            routed = prefill_routed = self.model.routed(cache)
             last = logits[:, -1:]               # (B, 1, V) | (B, 1, K, V)
             t_prefill = time.perf_counter()
 
@@ -94,6 +95,9 @@ class ServeEngine:
                         nxt = jnp.moveaxis(nxt, -1, 1)      # (B, K, 1)
                     outs.append(nxt)
                 with tracing.span("dispatch", step=i):
+                    if i == n_new - 1:
+                        # the count up to the last step whose token is kept
+                        routed = self.model.routed(cache)
                     idx = jnp.asarray(S + i, jnp.int32)
                     last, cache = self._decode(
                         self.params, cache,
@@ -103,6 +107,10 @@ class ServeEngine:
                         outs[i - 1] = np.asarray(outs[i - 1])
             with tracing.span("fetch", step=n_new - 1):
                 outs[-1] = np.asarray(outs[-1])
+                if routed is not None:
+                    pre = int(prefill_routed)
+                    tracing.count_routing(pre, int(routed) - pre, n_new - 1,
+                                          cfg.n_layers)
                 t_fetched = time.perf_counter()
         new = np.concatenate(outs, axis=-1)
         t_decode = t_fetched - t_prefill

@@ -1,4 +1,5 @@
-"""Spans and the compile counter of the serving engine.
+"""Spans, the compile counter and the routing counter of the serving
+engine.
 
 A span is a ``jax.profiler.TraceAnnotation`` named ``serve.<name>``: it
 lands in the profiler's own trace, on the clock of the device's events,
@@ -11,6 +12,13 @@ built by XLA or loaded from JAX's persistent compilation cache, as JAX's
 process-wide listener of that event, registered when the first engine
 is built, counts it if the compiling thread is inside a ``generate``
 call (``charged``); a compile anywhere else is not counted.
+
+The routing counter is the process's too: for an MoE model, how many
+experts got at least one row, summed over the MoE layers, in the
+prefills and in the decode steps of every ``generate`` call. A call's
+programs add it up on the device, and the call fetches it inside its
+last ``serve.fetch`` span: the last decode step, whose token is
+discarded, is not counted.
 """
 from __future__ import annotations
 
@@ -25,6 +33,8 @@ _lock = threading.Lock()
 _registered = False
 _compiles = 0
 _compile_s = 0.0
+_routing = dict.fromkeys(("prefill_runs", "prefill_experts", "decode_runs",
+                          "decode_experts", "moe_layers"), 0)
 
 
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:
@@ -64,3 +74,23 @@ def compiles() -> dict:
     process so far."""
     with _lock:
         return {"compiles": _compiles, "compile_s": _compile_s}
+
+
+def count_routing(prefill_experts: int, decode_experts: int,
+                  decode_runs: int, moe_layers: int) -> None:
+    """Add one call's prefill and ``decode_runs`` decode steps."""
+    with _lock:
+        _routing["prefill_runs"] += 1
+        _routing["prefill_experts"] += prefill_experts
+        _routing["decode_runs"] += decode_runs
+        _routing["decode_experts"] += decode_experts
+        _routing["moe_layers"] = moe_layers
+
+
+def routing() -> dict:
+    """``prefill_runs``, ``prefill_experts``, ``decode_runs``,
+    ``decode_experts`` of every ``generate`` call of this process so far,
+    and ``moe_layers`` of the model they served: experts routed to, per
+    layer and run, are ``decode_experts / (decode_runs * moe_layers)``."""
+    with _lock:
+        return dict(_routing)

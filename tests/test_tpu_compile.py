@@ -22,7 +22,7 @@ from repro.models.registry import build_model
 
 V5E_HBM_BYTES = 16 * 1024**3
 SERVE_BATCH, SERVE_PROMPT, SERVE_MAX_SEQ = 4, 128, 512
-MOE_CAPACITY = 128
+MOE_ROWS, MOE_ROW_TILE = 16 * 512 * 4, 256    # a (16, 512) prefill, top-4
 
 
 @pytest.fixture(scope="module")
@@ -72,15 +72,19 @@ def test_flash_attention_compiles_for_v5e(one_chip, dtype):
 
 
 def test_grouped_matmul_compiles_for_v5e(one_chip):
+    """The ragged kernel at Qwen1.5-MoE's widths, gate/up and down, on
+    the 6-layer stack of experts the served programs hand it."""
     cfg = ARCHS["qwen2-moe-a2.7b"]
-    x = _sds((cfg.n_experts, MOE_CAPACITY, cfg.d_model), jnp.bfloat16,
-             one_chip)
-    w = _sds((cfg.n_experts, cfg.d_model, cfg.d_expert), jnp.bfloat16,
-             one_chip)
-    kernel = functools.partial(moe_gmm.grouped_matmul, interpret=False)
-    compiled = jax.jit(kernel).lower(x, w).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    sizes = _sds((cfg.n_experts,), jnp.int32, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    kernel = functools.partial(moe_gmm.gmm, tm=MOE_ROW_TILE, interpret=False)
+    for d, f in ((cfg.d_model, cfg.d_expert), (cfg.d_expert, cfg.d_model)):
+        x = _sds((MOE_ROWS, d), jnp.bfloat16, one_chip)
+        w = _sds((6, cfg.n_experts, d, f), jnp.bfloat16, one_chip)
+        compiled = jax.jit(kernel).lower(x, w, sizes, layer).compile()
+        assert f"{moe_gmm.NAME}" in compiled.as_text()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
 @pytest.mark.parametrize("step", ["prefill", "decode"])
