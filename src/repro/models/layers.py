@@ -8,7 +8,8 @@ Conventions
 * ``dtype`` is the compute/param dtype (fp32 for CPU smoke tests,
   bf16 for the dry-run target); softmax/norm statistics in fp32.
 * KV caches: (B, S_max, n_kv, hd) per layer, stacked over layers by
-  the stacks' scan.
+  the stacks. Attention only reads a cache and returns its new K/V
+  rows (``attention_rows``); each stack writes them into its cache.
 """
 from __future__ import annotations
 
@@ -175,58 +176,73 @@ def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, S, H, hd)
 
 
-def attention_apply(
+def _sdpa_cached(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                 k: jax.Array, v: jax.Array,
+                 cache_index: jax.Array) -> jax.Array:
+    """One decode step's attention: q (B,1,H,hd) over the cache's
+    positions ``< cache_index`` and the step's own row k/v (B,1,Hkv,hd),
+    in one fp32 softmax, as ``_sdpa`` over the cache with the row
+    written at ``cache_index``; the cache is only read."""
+    B, S, H, hd = q.shape
+    T, Hkv = k_cache.shape[1], k_cache.shape[2]
+    qg = q.reshape(B, S, Hkv, H // Hkv, hd)
+    old = jnp.einsum("bskgh,btkh->bkgst", qg, k_cache).astype(jnp.float32)
+    old = jnp.where(jnp.arange(T) < cache_index, old / math.sqrt(hd), -1e30)
+    own = jnp.einsum("bskgh,bskh->bkgs", qg, k).astype(jnp.float32)
+    own = own / math.sqrt(hd)
+    # softmax over the T old positions and the own row together
+    m = jnp.maximum(jnp.max(old, axis=-1), own)
+    p_old = jnp.exp(old - m[..., None])
+    p_own = jnp.exp(own - m)
+    denom = jnp.sum(p_old, axis=-1) + p_own
+    w_old = (p_old / denom[..., None]).astype(v.dtype)
+    w_own = (p_own / denom).astype(v.dtype)
+    out = (jnp.einsum("bkgst,btkh->bskgh", w_old, v_cache,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgs,bskh->bskgh", w_own, v,
+                        preferred_element_type=jnp.float32))
+    return out.astype(v.dtype).reshape(B, S, H, hd)
+
+
+def attention_rows(
     p: Params,
     cfg: ModelConfig,
     x: jax.Array,
     positions: jax.Array,
     cache: Optional[Tuple[jax.Array, jax.Array]] = None,
     cache_index: Optional[jax.Array] = None,
-    causal: bool = True,
-    use_flash: bool = False,
-) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
-    """Full-sequence (train/prefill) or single-step (decode) attention.
+    use_flash: Any = False,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """Causal attention that leaves the cache to its caller: returns
+    (out, (k, v)), the K/V rows of ``x``, for the caller to write at
+    their positions.
 
-    cache: (k_cache, v_cache) each (B, S_max, n_kv, hd). In decode,
-    ``x`` is (B, 1, d) and ``cache_index`` the write position.
+    Without ``cache_index`` (training, prefill) ``x`` attends over its
+    own K/V. With it (decode, ``x`` (B, 1, d)) over ``cache``'s
+    positions before ``cache_index`` and its own row, so the step reads
+    the cache (each (B, S_max, n_kv, hd)) and writes nothing into it.
     """
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, cfg, x, positions)
-    new_cache = None
-    if cache is not None and cache_index is not None and S == 1:
+    if cache_index is not None:
         k_cache, v_cache = cache
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, cache_index, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, cache_index, 0, 0))
-        new_cache = (k_cache, v_cache)
-        T = k_cache.shape[1]
-        valid = jnp.arange(T)[None, None, None, None, :] <= cache_index
-        # low-precision KV caches (fp8) are upcast at read; scores/
-        # softmax math stays in the compute dtype
-        out = _sdpa(q, k_cache.astype(q.dtype), v_cache.astype(q.dtype),
-                    valid)
+        # the row as the cache holds it (fp8 caches round it), and the
+        # cache upcast at read; scores/softmax math stays in the
+        # compute dtype
+        k, v = k.astype(k_cache.dtype), v.astype(v_cache.dtype)
+        out = _sdpa_cached(q, k_cache.astype(q.dtype),
+                           v_cache.astype(q.dtype), k.astype(q.dtype),
+                           v.astype(q.dtype), cache_index)
+    elif use_flash == "pallas" and S >= 128:
+        from repro.kernels import ops as kops
+        out = kops.flash_attention(q, k, v, causal=True)
+    elif use_flash and S >= 256:
+        out = _sdpa_chunked(q, k, v, context_parallel=(use_flash == "cp"))
     else:
-        if use_flash == "pallas" and causal and S >= 128:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=True)
-        elif use_flash and causal and S >= 256:
-            out = _sdpa_chunked(q, k, v,
-                                context_parallel=(use_flash == "cp"))
-        else:
-            mask = None
-            if causal:
-                mask = jnp.tril(jnp.ones((S, S), bool))[None, None, None]
-            out = _sdpa(q, k, v, mask)
-        if cache is not None:
-            k_cache, v_cache = cache
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, 0, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, 0, 0, 0))
-            new_cache = (k_cache, v_cache)
+        mask = jnp.tril(jnp.ones((S, S), bool))[None, None, None]
+        out = _sdpa(q, k, v, mask)
     out = out.reshape(B, S, cfg.d_q) @ p["wo"]
-    return out, new_cache
+    return out, (k, v)
 
 
 # ----------------------------------------------------------------------

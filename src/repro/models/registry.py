@@ -6,7 +6,7 @@ serve layers program against.
   loss, metrics = model.loss(params, batch)              # training
   logits, cache = model.prefill(params, batch)           # serving
   logits, cache = model.decode_step(params, cache, batch)
-  model.routed(cache)        # MoE: experts routed to, on the device
+  count, cache = model.detach_routed(cache)  # MoE: experts routed to
 
 Batches are dicts:
   train:   {"tokens": (B,S) | (B,K,S), "targets": same, [patch_embeds]}
@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import stacks, transformer
@@ -97,7 +98,7 @@ class Model:
     # An MoE model's serving state is (k, v, routed): ``routed`` is an
     # int32 scalar that every prefill and decode step adds its experts
     # routed to (summed over layers) to, so the count stays on the
-    # device until a caller fetches it (``routed``).
+    # device until a caller takes it (``detach_routed``).
     def init_cache(self, batch: int, max_seq: int, dtype=jnp.float32):
         c = self.cfg
         if c.family == "moe":
@@ -154,10 +155,15 @@ class Model:
                                           cache_index=idx, decode=True)
         return lg, st
 
-    def routed(self, cache) -> Optional[jax.Array]:
-        """Experts routed to since ``init_cache``, summed over layers and
-        runs, as a device scalar; None for a model without routing."""
-        return cache[2] if self.cfg.family == "moe" else None
+    def detach_routed(self, cache) -> Tuple[Optional[jax.Array], Any]:
+        """(experts routed to since ``init_cache`` or the last detach,
+        summed over layers and runs, as a device scalar; the state with
+        its counter restarted at zero). The count stays the caller's to
+        read once the state is donated to a later step, which deletes
+        its buffers. (None, cache) for a model without routing."""
+        if self.cfg.family != "moe":
+            return None, cache
+        return cache[2], (*cache[:2], np.zeros((), np.int32))
 
     # ---------------- shape builders (dry-run / data pipeline) -------
     def batch_shapes(self, kind: str, batch: int, seq: int

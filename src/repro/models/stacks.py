@@ -193,8 +193,11 @@ def zamba2_forward(
     every = cfg.hybrid_attn_every
     x = jnp.take(p["embed"], tokens, axis=0)
     B, S = x.shape[0], x.shape[1]
-    if cache_index is not None and decode:
-        positions = jnp.full((B, 1), cache_index, jnp.int32)
+    # decode attends over the cache before ``cache_index`` and writes
+    # its row there; prefill attends over its own rows, written at 0
+    step = cache_index if decode else None
+    if step is not None:
+        positions = jnp.full((B, 1), step, jnp.int32)
     else:
         positions = jnp.arange(S, dtype=jnp.int32)[None, :].repeat(B, 0)
     with_state = state is not None
@@ -202,20 +205,20 @@ def zamba2_forward(
     def shared_block(args):
         xc, kv_k, kv_v, a_idx = args
         h = L.rmsnorm(p["shared_attn_norm"], xc, cfg.norm_eps)
+        read = None
+        if step is not None:
+            read = tuple(jax.lax.dynamic_index_in_dim(c, a_idx, 0,
+                                                      keepdims=False)
+                         for c in (kv_k, kv_v))
+        attn_out, rows = L.attention_rows(
+            p["shared_attn"], cfg, h, positions, cache=read,
+            cache_index=step, use_flash=use_flash)
         if with_state:
-            k_l = jax.lax.dynamic_index_in_dim(kv_k, a_idx, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(kv_v, a_idx, 0, keepdims=False)
-            attn_out, new_cache = L.attention_apply(
-                p["shared_attn"], cfg, h, positions, cache=(k_l, v_l),
-                cache_index=cache_index, causal=True, use_flash=use_flash)
-            kv_k = jax.lax.dynamic_update_index_in_dim(
-                kv_k, new_cache[0], a_idx, 0)
-            kv_v = jax.lax.dynamic_update_index_in_dim(
-                kv_v, new_cache[1], a_idx, 0)
-        else:
-            attn_out, _ = L.attention_apply(
-                p["shared_attn"], cfg, h, positions, causal=True,
-                use_flash=use_flash)
+            start = 0 if step is None else step
+            kv_k, kv_v = (
+                jax.lax.dynamic_update_slice(c, r[None].astype(c.dtype),
+                                             (a_idx, 0, start, 0, 0))
+                for c, r in zip((kv_k, kv_v), rows))
         xc = xc + attn_out
         h = L.rmsnorm(p["shared_mlp_norm"], xc, cfg.norm_eps)
         xc = xc + L.mlp_apply(p["shared_mlp"], cfg, h)

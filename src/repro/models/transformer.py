@@ -102,16 +102,18 @@ def _layer_apply(
     cache_index: Optional[jax.Array],
     use_flash: bool,
     experts: Optional[Params] = None,
-) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]], jax.Array,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array], jax.Array,
            Optional[jax.Array]]:
-    """(x, new cache, aux loss, experts routed to: MoE layers only).
-    ``experts``: every layer's routed experts, stacked, for an MoE layer
-    whose ``p_l["moe"]["layer"]`` says which is its own; without it an
-    MoE layer's experts are its own slice in ``p_l["moe"]``."""
+    """(x, the layer's new K/V rows, aux loss, experts routed to: MoE
+    layers only). ``cache_l`` is only read (decode); the caller writes
+    the rows. ``experts``: every layer's routed experts, stacked, for an
+    MoE layer whose ``p_l["moe"]["layer"]`` says which is its own;
+    without it an MoE layer's experts are its own slice in
+    ``p_l["moe"]``."""
     h = L.rmsnorm(p_l["attn_norm"], x, cfg.norm_eps)
-    attn_out, new_cache = L.attention_apply(
+    attn_out, rows = L.attention_rows(
         p_l["attn"], cfg, h, positions, cache=cache_l,
-        cache_index=cache_index, causal=True, use_flash=use_flash)
+        cache_index=cache_index, use_flash=use_flash)
     x = x + attn_out
     h = L.rmsnorm(p_l["mlp_norm"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
@@ -124,7 +126,7 @@ def _layer_apply(
         out, aux, routed = moe_apply(m, cfg, h, layer)
     else:
         out = L.mlp_apply(p_l["mlp"], cfg, h)
-    return x + out, new_cache, aux, routed
+    return x + out, rows, aux, routed
 
 
 # ----------------------------------------------------------------------
@@ -141,7 +143,12 @@ def forward(
 ) -> Tuple[jax.Array, Optional[Params], Dict[str, jax.Array]]:
     """Shared trunk. Returns (logits, new_cache, stats): ``stats["aux"]``
     is the auxiliary loss; an MoE model adds ``stats["experts"]``, (L,)
-    int32, how many experts each layer routed at least one row to."""
+    int32, how many experts each layer routed at least one row to.
+
+    With a cache, each layer returns only its new K/V rows and one
+    update per K and V writes them, all layers at once, at
+    ``cache_index`` (decode) or 0 (prefill): a caller that donates the
+    cache has it written in place, with no copy of it."""
     x = _embed_tokens(p, cfg, tokens)
     if cfg.family == "vlm" and patch_embeds is not None:
         x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
@@ -162,30 +169,28 @@ def forward(
         stacked = {k: moe.pop(k) for k in ("w_gate", "w_up", "w_down")}
         layers = dict(layers, moe=moe)
 
-    def body_nocache(carry, p_l):
-        xc, aux = carry
-        xc, _, aux_l, routed = _layer_apply(
-            cfg, p_l, xc, positions, None, None, use_flash, stacked)
-        return (xc, aux + aux_l), routed
+    # decode reads the cache; prefill attends over its own rows
+    read = cache if cache_index is not None else None
 
-    def body_cache(carry, xs):
+    def body(carry, xs):
         xc, aux = carry
         p_l, cache_l = xs
-        xc, new_cache_l, aux_l, routed = _layer_apply(
+        xc, rows, aux_l, routed = _layer_apply(
             cfg, p_l, xc, positions, cache_l, cache_index, use_flash,
             stacked)
-        return (xc, aux + aux_l), (new_cache_l, routed)
+        return (xc, aux + aux_l), (rows if cache is not None else None,
+                                   routed)
 
-    if cache is None:
-        body_fn = jax.checkpoint(body_nocache) if remat else body_nocache
-        (x, aux), routed = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)), layers)
-        new_cache = None
-    else:
-        body_fn = jax.checkpoint(body_cache) if remat else body_cache
-        (x, aux), (new_cache, routed) = jax.lax.scan(
-            body_fn, (x, jnp.zeros((), jnp.float32)),
-            (layers, cache))
+    body_fn = jax.checkpoint(body) if remat else body
+    (x, aux), (rows, routed) = jax.lax.scan(
+        body_fn, (x, jnp.zeros((), jnp.float32)), (layers, read))
+    new_cache = None
+    if cache is not None:
+        start = cache_index if cache_index is not None else 0
+        new_cache = tuple(
+            jax.lax.dynamic_update_slice(c, r.astype(c.dtype),
+                                         (0, 0, start, 0, 0))
+            for c, r in zip(cache, rows))
     if last_only:
         # serving prefill wants next-token logits only: slicing BEFORE
         # the unembed avoids materializing (B, S, V) logits.

@@ -45,8 +45,10 @@ class ServeEngine:
         if params is None:
             params = self.model.init(jax.random.PRNGKey(seed), dtype)
         self.params = params
-        self._prefill = jax.jit(self.model.prefill)
-        self._decode = jax.jit(self.model.decode_step)
+        # the cache is donated: each program writes its new K/V rows
+        # into it in place, and the caller never touches the old one
+        self._prefill = jax.jit(self.model.prefill, donate_argnums=(2,))
+        self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
         tracing.listen()
 
     def _sample(self, logits: jax.Array, key, temperature: float):
@@ -76,7 +78,7 @@ class ServeEngine:
                     (B, cfg.n_patches, cfg.d_model), jnp.float32)
             logits, cache = self._prefill(self.params, batch, cache)
             logits.block_until_ready()
-            routed = prefill_routed = self.model.routed(cache)
+            prefill_routed, cache = self.model.detach_routed(cache)
             last = logits[:, -1:]               # (B, 1, V) | (B, 1, K, V)
             t_prefill = time.perf_counter()
 
@@ -96,8 +98,8 @@ class ServeEngine:
                     outs.append(nxt)
                 with tracing.span("dispatch", step=i):
                     if i == n_new - 1:
-                        # the count up to the last step whose token is kept
-                        routed = self.model.routed(cache)
+                        # the count of the steps whose token is kept
+                        routed, cache = self.model.detach_routed(cache)
                     idx = jnp.asarray(S + i, jnp.int32)
                     last, cache = self._decode(
                         self.params, cache,
@@ -108,9 +110,8 @@ class ServeEngine:
             with tracing.span("fetch", step=n_new - 1):
                 outs[-1] = np.asarray(outs[-1])
                 if routed is not None:
-                    pre = int(prefill_routed)
-                    tracing.count_routing(pre, int(routed) - pre, n_new - 1,
-                                          cfg.n_layers)
+                    tracing.count_routing(int(prefill_routed), int(routed),
+                                          n_new - 1, cfg.n_layers)
                 t_fetched = time.perf_counter()
         new = np.concatenate(outs, axis=-1)
         t_decode = t_fetched - t_prefill

@@ -56,35 +56,43 @@ def test_smoke_train_step(arch):
 @pytest.mark.parametrize("arch", sorted(SMOKES))
 def test_decode_matches_prefill(arch):
     """Greedy decode logits must equal full-sequence forward logits —
-    the KV-cache/state machinery is exact."""
+    the KV-cache/state machinery is exact. Three consecutive steps
+    through programs that take the cache donated, as ``ServeEngine``
+    runs them: a row written at the wrong position, or a row the next
+    step cannot see, breaks a later step."""
     cfg = SMOKES[arch]
     model = build_model(cfg, remat=False)
     params = model.init(KEY)
-    B, S = 2, 16
+    B, S, n_dec = 2, 16, 3
     if cfg.family == "audio":
-        toks = jax.random.randint(KEY, (B, cfg.n_codebooks, S + 1), 0,
+        toks = jax.random.randint(KEY, (B, cfg.n_codebooks, S + n_dec), 0,
                                   cfg.vocab_size)
-        ctx, nxt = toks[..., :S], toks[..., S:]
     else:
-        toks = jax.random.randint(KEY, (B, S + 1), 0, cfg.vocab_size)
-        ctx, nxt = toks[:, :S], toks[:, S:]
-    batch = {"tokens": ctx}
+        toks = jax.random.randint(KEY, (B, S + n_dec), 0, cfg.vocab_size)
+    batch = {"tokens": toks[..., :S]}
     n_prefix = cfg.n_patches if cfg.family == "vlm" else 0
     if cfg.family == "vlm":
         batch["patch_embeds"] = jax.random.normal(
             KEY, (B, cfg.n_patches, cfg.d_model))
+    prefill = jax.jit(model.prefill, donate_argnums=(2,))
+    decode = jax.jit(model.decode_step, donate_argnums=(1,))
     cache = model.init_cache(B, S + n_prefix + 8)
-    lg_pre, cache = jax.jit(model.prefill)(params, batch, cache)
-    lg_dec, _ = jax.jit(model.decode_step)(
-        params, cache, {"tokens": nxt,
-                        "cache_index": jnp.asarray(S + n_prefix, jnp.int32)})
-    # reference: full forward over S+1 tokens
+    _, cache = prefill(params, batch, cache)
+    lg_dec = []
+    for i in range(n_dec):
+        lg, cache = decode(
+            params, cache, {"tokens": toks[..., S + i:S + i + 1],
+                            "cache_index": jnp.asarray(S + n_prefix + i,
+                                                       jnp.int32)})
+        lg_dec.append(np.asarray(lg[:, 0]))
+    # reference: full forward over S + n_dec tokens
     batch_ext = dict(batch, tokens=toks)
     cache2 = model.init_cache(B, S + n_prefix + 8)
     lg_full, _ = jax.jit(model.prefill)(params, batch_ext, cache2)
-    np.testing.assert_allclose(
-        np.asarray(lg_full[:, -1]), np.asarray(lg_dec[:, 0]),
-        rtol=2e-4, atol=2e-4)
+    for i in range(n_dec):
+        np.testing.assert_allclose(
+            np.asarray(lg_full[:, n_prefix + S + i]), lg_dec[i],
+            rtol=2e-4, atol=2e-4, err_msg=f"decode step {i}")
 
 
 @pytest.mark.parametrize("arch", sorted(SMOKES))

@@ -1,4 +1,6 @@
 """Serving: engine generation + the multi-tenant vNPU control plane."""
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -25,6 +27,46 @@ def test_engine_generate(arch):
     # greedy decode is deterministic
     res2 = eng.generate(prompt, n_new=4)
     np.testing.assert_array_equal(res.tokens, res2.tokens)
+
+
+def undonated_greedy(eng, prompt, n_new):
+    """Greedy tokens of the model's own programs, jitted without
+    donation, so every state stays readable."""
+    model, toks = eng.model, jnp.asarray(prompt, jnp.int32)
+    cache = model.init_cache(toks.shape[0], eng.max_seq, eng.dtype)
+    lg, cache = jax.jit(model.prefill)(eng.params, {"tokens": toks}, cache)
+    decode, out = jax.jit(model.decode_step), []
+    for i in range(n_new):
+        nxt = jnp.argmax(lg[:, -1:], axis=-1).astype(jnp.int32)
+        out.append(np.asarray(nxt))
+        lg, cache = decode(eng.params, cache, {
+            "tokens": nxt,
+            "cache_index": jnp.asarray(toks.shape[1] + i, jnp.int32)})
+    return np.concatenate(out, axis=-1)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "qwen2-moe-a2.7b"])
+def test_engine_donates_each_state_once(arch):
+    """The engine's programs take the cache donated and write their K/V
+    rows into it in place: two calls on one engine give the greedy
+    tokens of undonated programs, no call reads a donated (deleted)
+    buffer again, and each program consumes the state it is given."""
+    cfg = SMOKES[arch]
+    eng = ServeEngine(cfg, max_seq=32)
+    prompt = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 12))
+    first = eng.generate(prompt, n_new=5).tokens
+    np.testing.assert_array_equal(first, eng.generate(prompt, 5).tokens)
+    np.testing.assert_array_equal(first, undonated_greedy(eng, prompt, 5))
+
+    cache = eng.model.init_cache(2, eng.max_seq, eng.dtype)
+    lg, state = eng._prefill(eng.params,
+                             {"tokens": jnp.asarray(prompt, jnp.int32)}, cache)
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+    _, state2 = eng._decode(eng.params, state, {
+        "tokens": jnp.argmax(lg[:, -1:], -1).astype(jnp.int32),
+        "cache_index": jnp.asarray(12, jnp.int32)})
+    assert all(a.is_deleted() for a in jax.tree.leaves(state))
+    assert not any(a.is_deleted() for a in jax.tree.leaves(state2))
 
 
 def test_multitenant_server_end_to_end():

@@ -19,10 +19,12 @@ from repro.configs import ARCHS
 from repro.kernels import flash_attention as fa
 from repro.kernels import moe_gmm
 from repro.models.registry import build_model
+from repro.serve.engine import ServeEngine
 
 V5E_HBM_BYTES = 16 * 1024**3
 SERVE_BATCH, SERVE_PROMPT, SERVE_MAX_SEQ = 4, 128, 512
 MOE_ROWS, MOE_ROW_TILE = 16 * 512 * 4, 256    # a (16, 512) prefill, top-4
+CHAT_BATCH, CHAT_PROMPT, CHAT_MAX_SEQ = 32, 512, 1024
 
 
 @pytest.fixture(scope="module")
@@ -112,3 +114,41 @@ def test_qwen2_serve_step_fits_one_v5e(one_chip, step):
         lowered = jax.jit(model.decode_step).lower(params, cache, batch)
     compiled = lowered.compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_engine_steps_write_the_donated_cache_in_place(one_chip, step):
+    """The engine's own jitted programs, at qwen2-0.5b's widths and the
+    chat shapes: the cache comes back in its donated input's buffers,
+    and a decode step keeps no temporary as large as one layer's K
+    cache, so no whole-cache or whole-layer copy is left."""
+    cfg = ARCHS["qwen2-0.5b"]
+    bf16 = jnp.bfloat16
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    model = build_model(cfg, remat=False)
+    params = on_chip(jax.eval_shape(
+        lambda k: model.init(k, bf16), jax.random.PRNGKey(0)))
+    eng = ServeEngine(cfg, params=params, max_seq=CHAT_MAX_SEQ, dtype=bf16)
+    cache = on_chip(jax.eval_shape(
+        lambda: eng.model.init_cache(CHAT_BATCH, CHAT_MAX_SEQ, bf16)))
+    if step == "prefill":
+        batch = {"tokens": _sds((CHAT_BATCH, CHAT_PROMPT), jnp.int32,
+                                one_chip)}
+        compiled = eng._prefill.lower(params, batch, cache).compile()
+    else:
+        batch = {"tokens": _sds((CHAT_BATCH, 1), jnp.int32, one_chip),
+                 "cache_index": _sds((), jnp.int32, one_chip)}
+        compiled = eng._decode.lower(params, cache, batch).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert cache_bytes == 402_653_184
+    assert mem.alias_size_in_bytes >= cache_bytes
+    if step == "decode":
+        layer_k = (CHAT_BATCH * CHAT_MAX_SEQ * cfg.n_kv_heads * cfg.d_head
+                   * jnp.dtype(bf16).itemsize)
+        assert mem.temp_size_in_bytes < layer_k
