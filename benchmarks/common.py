@@ -12,8 +12,7 @@ from repro.core.policies import PolicyLike
 from repro.core.simulator import SimResult
 from repro.npu.hw_config import DEFAULT_CORE, NPUCoreConfig
 from repro.npu.workloads import PAPER_PAIRS, get_workload
-from repro.serve.session import (NPUCluster, build_closed_loop_specs,
-                                 run_closed_loop)
+from repro.serve.session import NPUCluster, run_closed_loop
 
 # the paper's four disciplines, in §V-A presentation order (all
 # resolved through the scheduler registry; extra registered policies
@@ -31,7 +30,6 @@ def run_pair(
     hbm_scale: float = 1.0,
     me_ve: Tuple[int, int] = (2, 2),
     fast_path: bool = True,
-    incremental: bool = True,
 ) -> SimResult:
     """Paper §V-A setup: two vNPUs of 2ME/2VE on a 4ME/4VE core,
     SRAM/HBM split evenly. The policy (any registry entry) picks the
@@ -46,29 +44,8 @@ def run_pair(
             VNPUConfig(*me_ve, hbm_bytes=core.hbm_bytes // 2,
                        sram_bytes=core.sram_bytes // 2))
     res, _ = run_closed_loop(cluster, n_requests=n_requests,
-                             hbm_scale=hbm_scale, fast_path=fast_path,
-                             incremental=incremental)
+                             hbm_scale=hbm_scale, fast_path=fast_path)
     return res
-
-
-def build_pair_specs(
-    w1: str,
-    w2: str,
-    policy: PolicyLike,
-    core: NPUCoreConfig = DEFAULT_CORE,
-    n_requests: int = 6,
-    me_ve: Tuple[int, int] = (2, 2),
-):
-    """Compile the :func:`run_pair` setup into reusable simulator
-    specs WITHOUT running — benchmark A/B rows compile once and time
-    only ``Simulator(specs, ...).run()`` per variant."""
-    cluster = NPUCluster(core=core, policy=policy)
-    for name in (w1, w2):
-        cluster.register_vnpu(
-            name, get_workload(name, core),
-            VNPUConfig(*me_ve, hbm_bytes=core.hbm_bytes // 2,
-                       sram_bytes=core.sram_bytes // 2))
-    return build_closed_loop_specs(cluster, n_requests)
 
 
 def geomean(xs) -> float:

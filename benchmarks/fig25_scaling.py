@@ -5,15 +5,12 @@ Paper claim: more MEs/VEs -> more benefit from μTOp scheduling.
 Also carries the simulator perf micro-benchmarks:
 
 * fast path — the largest sweep (8ME/8VE, the heaviest event load)
-  re-runs with ``fast_path=False`` (reference implementations:
-  unmemoized dispatch durations, engine-scan HBM pressure, reference
-  neu10 schedule pass) vs the default fast path, asserting the
-  SimResults are IDENTICAL and the wall-clock speedup is >= 1.3x
-  (min-of-N timings to reject machine noise).
-* sched_incremental — the same sweep with the dirty-set incremental
-  scheduling core (cohort dispatch + free-engine index) vs both the
-  reference and the PR-4 fast-path baselines, again with a 3-way
-  SimResult identity proof.
+  re-runs with ``fast_path=False`` (the reference engine: unmemoized
+  dispatch durations, engine-scan HBM pressure, the full neu10
+  schedule pass every event) vs the default engine (the dirty-set
+  scheduling core), asserting the SimResults are IDENTICAL and the
+  wall-clock speedup is >= 1.3x (min-of-N timings to reject machine
+  noise).
 * fleet_sweep — the fig25 outer grid (pairs × EU splits × bandwidth
   points) as ONE jitted ``sweep_collocations`` XLA program vs the
   discrete simulator running the same grid one cell at a time; this
@@ -24,11 +21,9 @@ from __future__ import annotations
 import time
 from typing import List
 
-from benchmarks.common import BenchRow, build_pair_specs, geomean, \
-    run_pair, timed
+from benchmarks.common import BenchRow, geomean, run_pair, timed
 from repro.core.policies import resolve_policy
 from repro.core.sim_jax import sweep_collocations
-from repro.core.simulator import Simulator
 from repro.core.vnpu import VNPUConfig
 from repro.npu.hw_config import NPUCoreConfig
 from repro.npu.workloads import get_workload
@@ -39,9 +34,7 @@ SIZES = [2, 4, 8]
 
 FAST_PATH_GAIN = 1.3   # required wall-clock speedup, largest sweep
 FAST_PATH_REPS = 5     # min-of-N per variant (noise rejection)
-
-INCREMENTAL_GAIN = 1.05   # incremental core vs the fast-path baseline
-FLEET_SWEEP_GAIN = 3.0    # jitted grid vs per-cell discrete (CI floor)
+FLEET_SWEEP_GAIN = 3.0   # jitted grid vs per-cell discrete (CI floor)
 
 # fleet-sweep grid: pairs × EU splits × HBM-bandwidth points on the
 # 8ME/8VE core (per-tenant (ME, VE) engine counts; asymmetric splits
@@ -52,8 +45,8 @@ SWEEP_REQUESTS = 4
 
 
 def _fast_path_row() -> BenchRow:
-    """Time the largest sweep (8ME/8VE BERT+ENet under neu10) with the
-    reference vs fast simulator paths; prove identical results."""
+    """Time the largest sweep (8ME/8VE BERT+ENet under neu10) on the
+    reference vs the default engine; prove identical results."""
     core = NPUCoreConfig(n_me=8, n_ve=8)
     times = {True: [], False: []}
     results = {}
@@ -76,42 +69,6 @@ def _fast_path_row() -> BenchRow:
         min(times[True]) * 1e6,
         f"speedup={speedup:.2f}x identical=True "
         f"ref_us={min(times[False]) * 1e6:.0f}")
-
-
-def _sched_incremental_row() -> BenchRow:
-    """The incremental dirty-set scheduling core on the largest sweep
-    vs BOTH baselines — the reference schedule pass and the PR-4 fast
-    path — with a 3-way SimResult identity proof. Specs compile ONCE
-    (``build_pair_specs``) so only ``Simulator(...).run()`` is
-    timed."""
-    core = NPUCoreConfig(n_me=8, n_ve=8)
-    specs = build_pair_specs("BERT", "ENet", "neu10", core=core,
-                             me_ve=(4, 4), n_requests=6)
-    variants = {"ref": (False, False), "fast": (True, False),
-                "inc": (True, True)}
-    times = {k: [] for k in variants}
-    results = {}
-    for _ in range(FAST_PATH_REPS):
-        for k, (fast, inc) in variants.items():
-            t0 = time.time()
-            res = Simulator(specs, policy="neu10", core=core,
-                            fast_path=fast, incremental=inc).run()
-            times[k].append(time.time() - t0)
-            results[k] = res
-    assert results["inc"] == results["ref"] == results["fast"], (
-        "incremental scheduling core diverged from the reference")
-    t = {k: min(v) for k, v in times.items()}
-    vs_ref = t["ref"] / max(t["inc"], 1e-9)
-    vs_fast = t["fast"] / max(t["inc"], 1e-9)
-    assert vs_fast >= INCREMENTAL_GAIN, (
-        f"incremental core {vs_fast:.2f}x < required "
-        f"{INCREMENTAL_GAIN}x over the fast path")
-    return BenchRow(
-        "fig25/sched_incremental/BERT+ENet/8ME8VE",
-        t["inc"] * 1e6,
-        f"speedup_vs_ref={vs_ref:.2f}x speedup_vs_fast={vs_fast:.2f}x "
-        f"identical=True ref_us={t['ref'] * 1e6:.0f} "
-        f"fast_us={t['fast'] * 1e6:.0f}")
 
 
 def _fleet_sweep_row() -> BenchRow:
@@ -199,7 +156,6 @@ def run() -> List[BenchRow]:
     # scaling trend: benefit at 8 engines >= benefit at 2 engines
     assert gains_by_size[8] >= gains_by_size[2] - 0.05
     rows.append(_fast_path_row())
-    rows.append(_sched_incremental_row())
     rows.append(_fleet_sweep_row())
     return rows
 

@@ -181,8 +181,8 @@ class SchedulerPolicy(ABC):
 
             def schedule_incremental(self, sim, t, dirty) -> None
 
-        When present (and the simulator runs with
-        ``fast_path=True, incremental=True``), the simulator calls it
+        When present (and the simulator runs its default engine,
+        ``fast_path=True``), the simulator calls it
         instead of ``schedule`` and ONLY when the dirty set is
         non-empty — ``dirty`` is the frozen set of tenant indices
         whose scheduling inputs changed since the last pass (-1 marks
@@ -238,11 +238,10 @@ class _SpatialPolicy(SchedulerPolicy):
     reclaim treats non-fused piggyback μTOps like any other harvested
     work (preemptible with the standard drain).
 
-    ``schedule`` has two result-identical implementations selected by
-    ``Simulator.fast_path``: the reference pass (kept for the A/B
-    equality + speedup proof in ``fig25_scaling``) and a tightened
-    pass that buckets free engines per owner once per pool and skips
-    empty ready queues."""
+    ``schedule`` is the reference pass: the ``fast_path=False``
+    engine runs it, and it is the oracle that the tests and
+    ``fig25_scaling``'s ``fast_path`` row hold the default engine's
+    :meth:`schedule_incremental` to."""
 
     spatial = True
     isa = "neuisa"
@@ -256,12 +255,6 @@ class _SpatialPolicy(SchedulerPolicy):
         self.recent_fused: Deque[FusedIssueGroup] = deque(maxlen=64)
 
     def schedule(self, sim: "Simulator", t: float) -> None:
-        if getattr(sim, "fast_path", False):
-            self._schedule_fast(sim, t)
-        else:
-            self._schedule_ref(sim, t)
-
-    def _schedule_ref(self, sim: "Simulator", t: float) -> None:
         tenants = sim.active_tenants()
         # 1) owners dispatch on their own engines (MEs then VEs)
         for pool, ready_attr in ((sim.mes, "ready_me"), (sim.ves, "ready_ve")):
@@ -333,116 +326,11 @@ class _SpatialPolicy(SchedulerPolicy):
                         self._try_fuse(sim, chunk, e.owner, rt)
                     sim.dispatch(chunk, [e], t, harvested=True)
 
-    def _schedule_fast(self, sim: "Simulator", t: float) -> None:
-        """Tightened schedule pass — decision-for-decision identical
-        to :meth:`_schedule_ref` (dispatch order, reclaim victims,
-        harvest order), with the per-tenant engine scans replaced by
-        one free-engine bucketing per pool (ownership is disjoint, so
-        dispatches never consume another owner's bucket) and empty
-        ready queues skipped outright."""
-        tenants = sim.active_tenants()
-        harvest = self.harvest
-        # `_dispatch` is aliased to `dispatch` at class-definition
-        # time, so comparing the two detects overrides made any way —
-        # instance patch (spies), subclass method, or class-level
-        # monkeypatch — without caching state that a patch could
-        # poison. Overridden dispatches route through the documented
-        # API so the observation point keeps seeing every chunk.
-        if ("dispatch" in sim.__dict__
-                or type(sim).dispatch is not type(sim)._dispatch):
-            def dispatch(c, e, t_, harvested=False):
-                sim.dispatch(c, [e], t_, harvested=harvested)
-        else:
-            dispatch = sim._dispatch1
-        # 1) owners dispatch on their own engines (MEs then VEs);
-        # 2) reclaim harvested μTOps squatting on needed engines.
-        # A pool sub-pass only runs when some tenant has ready work of
-        # that kind — with none, no dispatch NOR reclaim can happen,
-        # and (since only reclaim preemption refills ready queues
-        # mid-pass) none can appear either. Ready queues are stable
-        # list objects (mutated in place, never reassigned), so the
-        # (tenant, queue) pairing is taken once per sub-pass.
-        for is_ve, pool in ((False, sim.mes), (True, sim.ves)):
-            work = [(rt, rt.ready_ve if is_ve else rt.ready_me)
-                    for rt in tenants]
-            if not any(ready for _, ready in work):
-                continue
-            free_by_owner: Dict = {}
-            for e in pool:
-                if e.token < 0:
-                    free_by_owner.setdefault(e.owner, []).append(e)
-            for rt, ready in work:
-                if not ready:
-                    continue
-                if is_ve and len(ready) > 1:
-                    ready.sort(key=_ve_drain_first)
-                own_free = free_by_owner.get(rt.idx)
-                while own_free and ready:
-                    dispatch(ready.pop(0), own_free.pop(0), t)
-                # reclaim scan only when this owner actually has
-                # foreign chunks squatting on its engines (the
-                # simulator counts them incrementally) — with none the
-                # scan is a provable no-op
-                if harvest and ready and sim._squat.get(rt.idx):
-                    reclaimed = 0
-                    for e in pool:
-                        if reclaimed >= len(ready):
-                            break
-                        if (e.owner == rt.idx and e.token >= 0
-                                and e.chunk is not None
-                                and e.tenant != rt.idx):
-                            if e.chunk.fused:
-                                continue
-                            sim.preempt(e, t)
-                            reclaimed += 1
-                    if reclaimed:
-                        ctx = float(sim.core.ctx_switch_cycles
-                                    if pool is sim.mes else 32)
-                        rt.stats.reclaim_blocked += ctx
-        if not harvest:
-            return
-        # 3) harvest: leftover ready chunks take others' idle engines
-        # (same phase-aware decode-first order as the reference pass).
-        # Harvest only dispatches — no queue refills — so the src and
-        # free-engine snapshots taken here stay exhaustive; the token
-        # re-check covers engines consumed within this section.
-        for is_ve, pool in ((False, sim.mes), (True, sim.ves)):
-            src = [rt for rt in tenants
-                   if (rt.ready_ve if is_ve else rt.ready_me)]
-            if not src:
-                continue
-            free_list = [e for e in pool if e.token < 0]
-            if not free_list:
-                continue
-
-            def _order(r):
-                has_decode = any(c.phase == "decode" for c in
-                                 (r.ready_ve if is_ve else r.ready_me))
-                return (not has_decode, r.active_cycles)
-
-            for rt in sorted(src, key=_order):
-                ready = rt.ready_ve if is_ve else rt.ready_me
-                for e in free_list:
-                    if not ready:
-                        break
-                    if e.token >= 0 or e.owner == rt.idx:
-                        continue
-                    owner = (sim.tenants[e.owner]
-                             if e.owner is not None else None)
-                    if owner is not None and (owner.ready_ve if is_ve
-                                              else owner.ready_me):
-                        continue  # owner will use it this round
-                    chunk = ready.pop(0)
-                    if (self.fuse and is_ve and owner is not None
-                            and chunk.phase == "decode"):
-                        self._try_fuse(sim, chunk, e.owner, rt)
-                    dispatch(chunk, e, t, harvested=True)
-
     def schedule_incremental(self, sim: "Simulator", t: float,
                              dirty) -> None:
         """Dirty-set schedule pass (the incremental core's opt-in
-        hook): decision-for-decision identical to
-        :meth:`_schedule_fast` — the simulator already guarantees it
+        hook): decision-for-decision identical to the reference
+        :meth:`schedule` — the simulator already guarantees it
         only runs when ``dirty`` is non-empty, i.e. something changed
         a scheduling input since the last pass. On top of that gate it
         (a) consumes the simulator's maintained per-owner free-engine
@@ -462,12 +350,14 @@ class _SpatialPolicy(SchedulerPolicy):
         is: reclaim is bounded per pass (one ctx window per owner), so
         an owner still squatted-on with leftover ready work re-marks
         itself to keep the next pass coming. When ``dispatch`` is
-        overridden (spies/subclasses), the whole pass routes through
-        :meth:`_schedule_fast` so the documented API keeps seeing
-        every chunk."""
+        overridden (spies/subclasses) — ``_dispatch`` is aliased to
+        ``dispatch`` at class-definition time, so comparing the two
+        catches instance patches, subclass methods and class-level
+        monkeypatches — the whole pass routes through the reference
+        :meth:`schedule`, so the documented API sees every chunk."""
         if ("dispatch" in sim.__dict__
                 or type(sim).dispatch is not type(sim)._dispatch):
-            self._schedule_fast(sim, t)
+            self.schedule(sim, t)
             return
         act = sim._act
         harvest = self.harvest
@@ -482,9 +372,9 @@ class _SpatialPolicy(SchedulerPolicy):
         mes, ves = sim.mes, sim.ves
         left_me = left_ve = False   # pool has leftover ready work
                                     # after owner dispatch -> harvest
-        # 1) owner dispatch + 2) reclaim, MEs then VEs (the fast
-        # pass's structure; ready queues are stable list objects read
-        # live, exactly like the fast pass's work-list snapshot)
+        # 1) owner dispatch + 2) reclaim, MEs then VEs (the reference
+        # pass's order; ready queues are stable list objects, mutated
+        # in place and read live)
         for is_ve in (False, True):
             if is_ve:
                 free_own, kind, nd = sim._free_ve_own, VE, 0
@@ -603,9 +493,9 @@ class _SpatialPolicy(SchedulerPolicy):
                 sim._nfree_me -= nd
         if not harvest:
             return
-        # 3) harvest — identical structure and order to _schedule_fast
-        # (the leftover flags + maintained free counters make the
-        # common nothing-to-harvest case two boolean checks per pool)
+        # 3) harvest — same order as the reference pass (the leftover
+        # flags + maintained free counters make the common
+        # nothing-to-harvest case two boolean checks per pool)
         for is_ve in (False, True):
             if not (left_ve if is_ve else left_me):
                 continue

@@ -45,12 +45,14 @@ into a single program compiled on demand on a quantized grid — see
 the slice sizing and token-accounting rules. Unset, the PR-3 phase
 chain engine runs verbatim.
 
-``Simulator(fast_path=...)`` selects between the reference event-loop
-implementations and result-identical optimized ones (memoized
-dispatch spans, incremental HBM-contention and harvest-squatter
-bookkeeping, precomputed μTOp expansion, the tightened neu10 schedule
-pass); ``benchmarks/fig25_scaling.py`` pins both the equality and the
-speedup.
+The simulator has two engines. The default (``fast_path=True``) is
+the optimised one: memoized dispatch spans, incremental HBM-contention
+and harvest-squatter bookkeeping, precomputed μTOp expansion, and the
+dirty-set schedule pass of policies that define
+``schedule_incremental``. ``Simulator(fast_path=False)`` runs the
+reference implementations, kept as the oracle the tests and
+``benchmarks/fig25_scaling.py``'s ``fast_path`` row hold the default
+engine to, result for result.
 
 With ``TenantSpec.kv_policy`` set, KV-cache occupancy is a live
 simulator resource: every prefill chunk/slice and decode token
@@ -431,7 +433,7 @@ class _TenantRT:
         self.spec = spec
         self.core = core
         self.open_loop = open_loop
-        self.fast_path = fast_path
+        self.fast_path: bool = fast_path
         self.removed = False
         if spec.plan is not None:
             self.plan = spec.plan
@@ -1533,34 +1535,27 @@ class Simulator:
         fair_slice: float = 50_000.0,   # cycles of service imbalance
         max_events: int = 20_000_000,
         fast_path: bool = True,
-        incremental: bool = True,
     ):
-        """``fast_path`` enables the wall-clock optimizations that are
-        *result-identical* by construction: memoized per-(chunk shape,
-        mem-pressure) dispatch durations, incremental HBM-contention
-        bookkeeping instead of an engine scan per dispatch, and the
-        tightened ``neu10`` schedule pass. ``False`` runs the
-        reference implementations — kept so benchmarks/tests can
-        prove byte-for-byte SimResult equality and measure the
-        speedup (``fig25_scaling``'s fast-path row).
-
-        ``incremental`` additionally enables dirty-set scheduling when
-        the policy opts in with ``schedule_incremental`` (see
+        """``fast_path`` (the default) selects the optimised engine:
+        memoized per-(chunk shape, mem-pressure) dispatch durations,
+        incremental HBM-contention bookkeeping instead of an engine
+        scan per dispatch, and dirty-set scheduling when the policy
+        opts in with ``schedule_incremental`` (see
         ``docs/architecture.md`` "Event engine"): the schedule pass is
         skipped outright while nothing marked a tenant dirty, runs
         through the policy's incremental hook otherwise, and the event
         loop/dispatch layers are fused into one frame. Policies
-        without the hook (``pmt``/``v10``) silently fall back to the
-        full pass per event. Result-identical by the same A/B proof
-        (``fig25``'s ``sched_incremental`` row + the churn property
-        test); requires ``fast_path``."""
+        without the hook (``pmt``/``v10``) run their full pass per
+        event. ``False`` runs the reference engine — the oracle that
+        the tests and ``fig25_scaling``'s ``fast_path`` row compare
+        the default against, byte for byte."""
         self.policy_obj = resolve_policy(policy)
         self.policy = self.policy_obj.name or type(self.policy_obj).__name__
         self.core = core
         self.hbm_scale = hbm_scale
         self.fair_slice = fair_slice
         self.max_events = max_events
-        self.fast_path = fast_path
+        self.fast_path: bool = fast_path
         self._span_memo: Dict[Tuple, float] = {}
         self._bw_inflight: Dict[int, int] = {}   # id(chunk) -> tenant
         self._bw_per_tenant: Dict[int, int] = {}  # tenant -> bw chunks
@@ -1600,7 +1595,6 @@ class Simulator:
         # indices; -1 = global change such as an unowned engine
         # freeing) and the per-owner free-engine index the incremental
         # pass consumes instead of scanning pools
-        self.incremental = incremental
         self._dirty: set = set()
         self._free_me_own: Dict[Optional[int], List[_Engine]] = {}
         self._free_ve_own: Dict[Optional[int], List[_Engine]] = {}
@@ -1609,8 +1603,7 @@ class Simulator:
         self._nfree_me = 0
         self._nfree_ve = 0
         self._inc_fn = getattr(self.policy_obj, "schedule_incremental", None)
-        self._inc = bool(incremental and fast_path
-                         and self._inc_fn is not None)
+        self._inc = fast_path and self._inc_fn is not None
         if self._inc:
             self._rebuild_free_index()
         self.policy_obj.on_attach(self)
@@ -2392,16 +2385,19 @@ class Simulator:
     def dispatch(self, chunk: Chunk, engines: List[_Engine], t: float,
                  harvested: bool = False) -> None:
         """Start ``chunk`` on one or more free engines at time ``t``."""
-        if len(engines) == 1:
-            # single source of truth for the 1-engine case
-            self._dispatch1(chunk, engines[0], t, harvested)
-            return
         token = next(self._tok)
         n = len(engines)
-        dur = self._duration(chunk, n)
+        fast = self.fast_path
+        if (fast and n == 1 and chunk.hbm_bytes <= 0
+                and self.tenants[chunk.tenant].is_neuisa):
+            # the common compute-only μTOp: its span needs no lookup
+            dur = chunk.cycles + chunk.penalty
+        else:
+            dur = self._duration(chunk, n)
+            if fast:
+                self._bw_register(chunk)
         chunk.n_dispatched = n
         end = t + dur
-        fast = self.fast_path
         inc = self._inc
         for e in engines:
             if inc:
@@ -2414,42 +2410,9 @@ class Simulator:
             e.harvested = harvested
             if fast and e.owner is not None and e.owner != chunk.tenant:
                 self._squat[e.owner] = self._squat.get(e.owner, 0) + 1
-        if fast:
-            self._bw_register(chunk)
         lead = engines[0]
         heapq.heappush(
             self._heap, (end, next(self._seq), lead.kind, lead.eid, token))
-
-    def _dispatch1(self, chunk: Chunk, e: _Engine, t: float,
-                   harvested: bool = False) -> None:
-        """Single-engine dispatch (same semantics as
-        ``dispatch(chunk, [e], t, harvested)``, which delegates here —
-        the hot schedule pass calls it directly to skip the list
-        plumbing, and the common compute-only μTOp duration is
-        inlined)."""
-        token = next(self._tok)
-        fast = self.fast_path
-        if (fast and chunk.hbm_bytes <= 0
-                and self.tenants[chunk.tenant].is_neuisa):
-            dur = chunk.cycles + chunk.penalty
-        else:
-            dur = self._duration(chunk, 1)
-            if fast:
-                self._bw_register(chunk)
-        chunk.n_dispatched = 1
-        if self._inc:
-            self._free_idx_remove(e)
-        e.token = token
-        e.chunk = chunk
-        e.tenant = chunk.tenant
-        e.start = t
-        end = t + dur
-        e.end = end
-        e.harvested = harvested
-        if fast and e.owner is not None and e.owner != chunk.tenant:
-            self._squat[e.owner] = self._squat.get(e.owner, 0) + 1
-        heapq.heappush(
-            self._heap, (end, next(self._seq), e.kind, e.eid, token))
 
     def _bw_register(self, chunk: Chunk) -> None:
         """Incremental HBM-contention bookkeeping (fast path): the

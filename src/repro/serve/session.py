@@ -780,21 +780,17 @@ def build_closed_loop_specs(cluster: NPUCluster,
 
 def run_closed_loop(cluster: NPUCluster, n_requests: int = 8,
                     hbm_scale: float = 1.0, fast_path: bool = True,
-                    incremental: bool = True,
                     ) -> Tuple[SimResult, List[TenantReport]]:
     """Batch-mode run: every registered tenant replays its program
     ``n_requests`` times back to back (the paper's §V-A methodology).
     Generative tenants replay their full phase chain (prefill + the
     default generation length of decode steps) per request.
-    ``fast_path=False`` selects the simulator's reference
-    implementations (result-identical; see :class:`Simulator`) — the
-    fig25 fast-path benchmark row uses it for its A/B proof;
-    ``incremental=False`` likewise disables the dirty-set scheduling
-    core (the ``sched_incremental`` row's baseline)."""
+    ``fast_path=False`` selects the simulator's reference engine
+    (result-identical; see :class:`Simulator`) — the fig25 fast-path
+    benchmark row uses it for its A/B proof."""
     specs = build_closed_loop_specs(cluster, n_requests)
     res = Simulator(specs, policy=cluster.policy_cls, core=cluster.core,
-                    hbm_scale=hbm_scale, fast_path=fast_path,
-                    incremental=incremental).run()
+                    hbm_scale=hbm_scale, fast_path=fast_path).run()
     return res, reports_from_result(cluster.tenants, res, cluster.core)
 
 
@@ -1008,11 +1004,15 @@ class ServingSession:
     def __init__(self, cluster: NPUCluster, hbm_scale: float = 1.0,
                  fair_slice: float = 50_000.0,
                  autoscaler: Optional[AutoscaleHook] = None,
-                 incremental: bool = True,
+                 fast_path: bool = True,
                  faults: Optional[FaultSchedule] = None,
                  failover: str = "evacuate",
                  admission: Optional[AdmissionController] = None):
-        """``faults`` injects a deterministic
+        """``fast_path=False`` builds every core's :class:`Simulator`
+        on the reference engine, the oracle the default one is tested
+        against.
+
+        ``faults`` injects a deterministic
         :class:`~repro.core.faults.FaultSchedule` into the run (event
         times and recovery windows in SECONDS, the session's API
         domain): core failures, per-link bandwidth degradation or
@@ -1058,7 +1058,7 @@ class ServingSession:
         self.sims: List[Simulator] = [
             Simulator((), policy=cluster.policy_cls, core=cluster.core,
                       hbm_scale=hbm_scale, fair_slice=fair_slice,
-                      incremental=incremental)
+                      fast_path=fast_path)
             for _ in cluster.manager.cores
         ]
         self.sim = self.sims[0]   # single-core back-compat alias

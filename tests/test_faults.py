@@ -1,7 +1,7 @@
 """Fault injection + failover: schedule determinism, fabric / mapper
 fault primitives, whole-vNPU evacuation round-trips, kill-and-restart
 suspension with deadline/retry re-admission, graceful HBM degradation,
-fault-free golden bit-identity across all three engines, and a
+fault-free golden bit-identity across both engines, and a
 property suite interleaving chaos with arrivals / resizes / borrows."""
 import pytest
 
@@ -122,10 +122,7 @@ def _serve(faults=None, failover="evacuate", n_cores=4, retry=True,
     topo = FabricTopology.mesh(n_cores, LINK)
     cluster = NPUCluster(core=CORE, policy="neu10", topology=topo)
     sess = ServingSession(cluster, faults=faults, failover=failover,
-                          incremental=(engine != "full"))
-    if engine == "ref":
-        for s in sess.sims:
-            s.fast_path = False
+                          fast_path=(engine != "ref"))
     kw = dict(deadline_ms=deadline_ms, max_retries=max_retries,
               retry_backoff_ms=0.05) if retry else {}
     kw.update(reg_kw)
@@ -264,12 +261,12 @@ def test_evacuation_carries_shared_prefix_and_retention():
 
 
 # ----------------------------------------------------------------------
-# fault-free golden bit-identity (all three engines)
+# fault-free golden bit-identity (both engines)
 # ----------------------------------------------------------------------
 # Captured from the PR 9 tree on the burst scenario above: with every
 # fault knob off — no schedule, deadline/retry/retention zero — the
 # failover-capable session must not perturb a single event, for the
-# incremental, full-rebuild AND reference (fast_path off) engines, and
+# default (incremental) AND reference (fast_path off) engines, and
 # an EMPTY schedule must match no schedule at all.
 FAULT_OFF_GOLDEN = [24, 768, 0.332126]
 
@@ -280,7 +277,7 @@ def _fingerprint(sess, chat):
             round(max(s.now for s in sess.sims) / CORE.freq_hz * 1e3, 6)]
 
 
-@pytest.mark.parametrize("engine", ["inc", "full", "ref"])
+@pytest.mark.parametrize("engine", ["inc", "ref"])
 @pytest.mark.parametrize("empty_schedule", [False, True])
 def test_fault_free_golden_bit_identical(engine, empty_schedule):
     faults = FaultSchedule([]) if empty_schedule else None

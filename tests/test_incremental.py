@@ -1,9 +1,10 @@
-"""Incremental dirty-set scheduling core: result identity against the
-reference and fast-path engines (per policy, on the cohort-heavy
-config, under KV pressure, and under randomized mid-run churn), plus
-event-heap hygiene (lazy deletion stays bounded; compaction is
-result-invariant) and the cluster event heap's idle-core migration
-wake-up."""
+"""The default engine (the incremental dirty-set scheduling core):
+result identity against the reference engine, ``fast_path=False``
+(per policy, on the cohort-heavy config, under an overridden
+``dispatch``, under KV pressure, and under randomized mid-run churn),
+the session's engine selection, event-heap hygiene (lazy deletion
+stays bounded; compaction is result-invariant) and the cluster event
+heap's idle-core migration wake-up."""
 import math
 
 import pytest
@@ -41,15 +42,12 @@ def _pair_specs(policy, core, me_ve=(2, 2), n_requests=4,
     return specs
 
 
-def _three_way(specs, policy, core):
-    """ref (fast_path off) / fast (PR-4) / inc (dirty-set core)."""
+def _two_way(specs, policy, core):
+    """ref (fast_path off) / inc (the default, dirty-set core)."""
     ref = Simulator(specs, policy=policy, core=core,
                     fast_path=False).run()
-    fast = Simulator(specs, policy=policy, core=core,
-                     incremental=False).run()
-    inc = Simulator(specs, policy=policy, core=core,
-                    incremental=True).run()
-    return ref, fast, inc
+    inc = Simulator(specs, policy=policy, core=core).run()
+    return ref, inc
 
 
 # ----------------------------------------------------------------------
@@ -62,29 +60,71 @@ def test_incremental_identical_per_policy(policy):
     incremental dispatch core."""
     core = DEFAULT_CORE
     specs = _pair_specs(policy, core)
-    ref, fast, inc = _three_way(specs, policy, core)
-    assert inc == fast == ref
+    ref, inc = _two_way(specs, policy, core)
+    assert inc == ref
 
 
-def test_incremental_identical_on_cohort_config():
+COHORT_CORE = NPUCoreConfig(n_me=8, n_ve=8)
+
+
+def _cohort_specs(policy):
+    return _pair_specs(policy, COHORT_CORE, me_ve=(4, 4), n_requests=6)
+
+
+@pytest.mark.parametrize("policy", ["neu10", "neu10_nh"])
+def test_incremental_identical_on_cohort_config(policy):
     """The 8ME/8VE half-split sweep is the cohort-heavy load (runs of
     identical non-contender chunks dispatched under one completion
     event) — exactness there pins the batched completion path."""
-    core = NPUCoreConfig(n_me=8, n_ve=8)
-    specs = _pair_specs("neu10", core, me_ve=(4, 4), n_requests=6)
-    ref, fast, inc = _three_way(specs, "neu10", core)
-    assert inc == fast == ref
+    ref, inc = _two_way(_cohort_specs(policy), policy, COHORT_CORE)
+    assert inc == ref
+
+
+@pytest.mark.parametrize("policy", ["neu10", "neu10_nh"])
+def test_overridden_dispatch_sees_every_chunk(policy):
+    """An instance-patched ``dispatch`` (a spy) sends the default
+    engine's schedule pass through the reference pass, so the spy
+    sees chunks of every tenant — and the SimResult is the one the
+    unpatched engine gives."""
+    specs = _cohort_specs(policy)
+    plain = Simulator(specs, policy=policy, core=COHORT_CORE).run()
+    sim = Simulator(specs, policy=policy, core=COHORT_CORE)
+    seen = set()
+    orig = sim.dispatch
+
+    def spy(chunk, engines, t, harvested=False):
+        seen.add(chunk.tenant)
+        return orig(chunk, engines, t, harvested)
+
+    sim.dispatch = spy
+    assert sim.run() == plain
+    assert seen == {0, 1}
+
+
+def test_session_fast_path_selects_engine_on_every_core():
+    """``ServingSession(fast_path=False)`` builds every core's
+    simulator on the reference engine; the default builds them all
+    on the incremental core."""
+    def sims(**kw):
+        return ServingSession(
+            NPUCluster(core=KV_CORE, policy="neu10",
+                       topology=FabricTopology.mesh(4)), **kw).sims
+
+    ref, inc = sims(fast_path=False), sims()
+    assert len(ref) == len(inc) == 4
+    assert all(not s.fast_path and not s._inc for s in ref)
+    assert all(s.fast_path and s._inc for s in inc)
 
 
 _WSEG = -(-(CHAT.param_count() * 2) // SEG) * SEG   # weights, rounded
 
 
-def _kv_pressure_result(incremental):
+def _kv_pressure_result(fast_path):
     """Decode-heavy open-loop burst on a weights + 2-segment HBM pin:
     concurrent decodes overflow the ledger, forcing evict/swap-resume
     round trips (the fig_kv_pressure mix)."""
     sess = ServingSession(NPUCluster(core=KV_CORE, policy="neu10"),
-                          incremental=incremental)
+                          fast_path=fast_path)
     chat = sess.register_generative(
         "chat", CHAT, prompt_len=128,
         gen_lens=GenLenDistribution(mean=96.0, max_len=256, seed=11),
@@ -121,13 +161,13 @@ _CHURN_CORE = NPUCoreConfig(n_me=4, n_ve=4)
 _CHURN_TRACES = ("DLRM", "ENet")
 
 
-def _churn_result(ops, incremental):
+def _churn_result(ops, fast_path):
     """Replay one churn script on a fresh open-loop simulator. Every
     side effect is keyed to an ABSOLUTE time cursor (never sim.now),
     so the script is replayable independent of engine internals."""
     cluster = NPUCluster(core=_CHURN_CORE, policy="neu10")
     sim = Simulator((), policy="neu10", core=_CHURN_CORE,
-                    incremental=incremental)
+                    fast_path=fast_path)
     active = []          # (handle, sim_idx)
     t = 0.0
     serial = 0
@@ -174,7 +214,7 @@ def _churn_result(ops, incremental):
 def test_property_churn_incremental_identity(ops):
     """Under arbitrary interleavings of arrivals, tenant adds/removes
     and live resizes, the incremental core's SimResult is bit-
-    identical to the full-pass engine."""
+    identical to the reference engine."""
     assert _churn_result(ops, True) == _churn_result(ops, False)
 
 
@@ -260,11 +300,11 @@ def test_compaction_threshold_is_result_invariant():
 # ----------------------------------------------------------------------
 # cluster event heap (ServingSession._advance)
 # ----------------------------------------------------------------------
-def _fabric_run(incremental):
+def _fabric_run(fast_path):
     sess = ServingSession(
         NPUCluster(core=KV_CORE, policy="neu10",
                    topology=FabricTopology.ring(4)),
-        incremental=incremental)
+        fast_path=fast_path)
     ft = sess.register_generative(
         "chat", CHAT, prompt_len=128, gen_lens=8, eu_budget=4,
         placement=Placement(prefill_core=0, decode_core=2),
@@ -278,8 +318,8 @@ def test_cluster_heap_wakes_idle_migration_target():
     """The decode core starts with an INFINITE event horizon (no
     local work): only the migration hook's re-key can wake it in the
     cluster event heap. Every hand-off must land and decode there —
-    and the whole run must be identical with the incremental core on
-    or off."""
+    and the whole run must be identical on the default and the
+    reference engine."""
     sess, ft = _fabric_run(True)
     r = sess.report(ft)[0]
     assert r.requests_done == 16

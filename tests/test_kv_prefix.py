@@ -1,6 +1,6 @@
 """Refcounted cross-request KV prefix sharing + cross-tenant HBM
 borrowing: ledger property suite vs a mirror model, sharing-off golden
-bit-identity across all three engines, composition tests (shared-
+bit-identity across both engines, composition tests (shared-
 holder eviction, borrow/reclaim ordering, cross-core migration of a
 shared-prefix holder), and the shrink-with-resident-shared-segments
 resize regression."""
@@ -253,17 +253,25 @@ def test_acquire_shared_size_mismatch_raises():
 
 
 # ----------------------------------------------------------------------
-# sharing-off golden bit-identity (all three engines)
+# sharing-off golden bit-identity (both engines)
 # ----------------------------------------------------------------------
 # Captured from the PR 7 tree on the fixed pressure / fabric scenarios
 # below: with no prefix_profile and no kv_borrow, the sharing-capable
 # engine must not perturb a single event — every counter, latency sum
-# and the final event clock stay byte-identical, for the incremental,
-# full-rebuild AND reference (fast_path off) engines.
+# and the final event clock stay byte-identical, for the default
+# (incremental) AND reference (fast_path off) engines.
+#
+# "pressure/evict"'s tbt sum (10th field) is re-pinned from the
+# captured 8195041.509125 to 8195041.509126, the value both engines
+# give: the unrounded sum of its 2,108 terms is 8195041.509125591,
+# 9.1e-8 (about 49 ulps) above the rounding boundary .5091255, so the
+# capture's floating-point environment rounded it the other way. The
+# captured value fails at the capturing commit too; every counter and
+# the other three sums match as captured.
 PREFIX_OFF_GOLDEN = {
     "pressure/evict": [24, 2132, 30, 30, 0, 0, 0,
                        12212025.457979, 4016983.948854,
-                       8195041.509125, 1624303.296264],
+                       8195041.509126, 1624303.296264],
     "pressure/reject": [24, 3088, 42, 0, 42, 0, 0,
                         17339038.22723, 9544632.355104,
                         6852367.88375, 2498489.253263],
@@ -275,10 +283,7 @@ PREFIX_OFF_GOLDEN = {
 
 def _pressure_stats(kv_policy, engine):
     cluster = NPUCluster(core=SMALL_CORE, policy="neu10")
-    sess = ServingSession(cluster, incremental=(engine != "full"))
-    if engine == "ref":
-        for s in sess.sims:
-            s.fast_path = False
+    sess = ServingSession(cluster, fast_path=(engine != "ref"))
     chat = sess.register_generative(
         "chat", CFG, prompt_len=128,
         gen_lens=GenLenDistribution(mean=96.0, max_len=256, seed=11),
@@ -299,10 +304,7 @@ def _fabric_stats(engine):
     sess = ServingSession(
         NPUCluster(core=SMALL_CORE, policy="neu10",
                    topology=FabricTopology.mesh(4)),
-        incremental=(engine != "full"))
-    if engine == "ref":
-        for s in sess.sims:
-            s.fast_path = False
+        fast_path=(engine != "ref"))
     ft = sess.register_generative(
         "chat", CFG, prompt_len=128, gen_lens=8, eu_budget=4,
         placement=Placement(), kv_policy="evict", hbm_bytes=256 * SEG)
@@ -319,14 +321,14 @@ def _fabric_stats(engine):
     return out
 
 
-@pytest.mark.parametrize("engine", ["inc", "full", "ref"])
+@pytest.mark.parametrize("engine", ["inc", "ref"])
 @pytest.mark.parametrize("kv_policy", ["evict", "reject"])
 def test_sharing_off_pressure_goldens_bit_identical(kv_policy, engine):
     assert (_pressure_stats(kv_policy, engine)
             == PREFIX_OFF_GOLDEN[f"pressure/{kv_policy}"])
 
 
-@pytest.mark.parametrize("engine", ["inc", "full", "ref"])
+@pytest.mark.parametrize("engine", ["inc", "ref"])
 def test_sharing_off_fabric_golden_bit_identical(engine):
     assert _fabric_stats(engine) == PREFIX_OFF_GOLDEN["fabric"]
 
@@ -337,10 +339,7 @@ def test_sharing_off_fabric_golden_bit_identical(engine):
 def _sharing_session(kv_segs=4, ratio=1.0, n=24, engine="inc",
                      gen_mean=96.0, **reg_kw):
     cluster = NPUCluster(core=SMALL_CORE, policy="neu10")
-    sess = ServingSession(cluster, incremental=(engine != "full"))
-    if engine == "ref":
-        for s in sess.sims:
-            s.fast_path = False
+    sess = ServingSession(cluster, fast_path=(engine != "ref"))
     chat = sess.register_generative(
         "chat", CFG, prompt_len=128,
         gen_lens=GenLenDistribution(mean=gen_mean, max_len=256, seed=11),
@@ -362,13 +361,13 @@ def _sharing_fingerprint(sess, chat):
 
 def test_same_seed_hit_miss_sequence_is_deterministic():
     """Two identical sharing runs produce the SAME hit/miss sequence,
-    counters and event clock — and all three engines agree."""
+    counters and event clock — and both engines agree."""
     prints = []
-    for engine in ("inc", "inc", "full", "ref"):
+    for engine in ("inc", "inc", "ref"):
         sess, chat = _sharing_session(engine=engine)
         sess.drain()
         prints.append(_sharing_fingerprint(sess, chat))
-    assert prints[0] == prints[1] == prints[2] == prints[3]
+    assert prints[0] == prints[1] == prints[2]
     assert prints[0][2] > 0                   # hits actually happened
 
 

@@ -390,7 +390,7 @@ def test_fast_path_identical_across_mid_run_tenant_churn():
 def test_fast_schedule_honors_subclassed_dispatch():
     """A Simulator subclass overriding dispatch() (the documented
     policy-facing API) must keep seeing every chunk even though the
-    fast neu10 pass normally uses the internal single-engine path."""
+    default engine's neu10 pass normally dispatches inline."""
     from repro.core.simulator import Simulator, TenantSpec
 
     seen = []
